@@ -32,5 +32,5 @@ bench:
 	$(PY) -m benchmarks.run
 
 bench-index:
-	$(PY) -m benchmarks.index_scale
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 $(PY) -m benchmarks.index_scale
 	$(PY) -m benchmarks.check_regression

@@ -43,7 +43,6 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -95,6 +94,9 @@ class RefreshScheduler:
         self.n_epochs = 0
         self.n_blocking = 0       # queries that waited for a refresh
         self.n_stale_served = 0   # queries served a lagging snapshot
+        # the exception that stopped the background thread, re-raised by
+        # every later query
+        self.failure: Optional[BaseException] = None
         if thread:
             self.start()
 
@@ -202,6 +204,9 @@ class RefreshScheduler:
         nothing was ever published)."""
         if freshness not in (None, "fresh", "stale"):
             raise ValueError(f"freshness={freshness!r}")
+        if self.failure is not None:
+            raise RuntimeError("background bank refresh failed") \
+                from self.failure
         bank = self.store._bank
         snap = None if bank is None else bank.published
         if snap is not None and freshness == "stale":
@@ -260,7 +265,8 @@ class RefreshScheduler:
                 # should land now rather than one idle period apart
                 while self.store.ivf_maybe_recluster() and not self._stop:
                     pass
-            except Exception as e:  # keep the daemon alive; dirt was requeued
-                warnings.warn(f"bank refresh epoch failed: {e!r}",
-                              RuntimeWarning)
-                time.sleep(self._idle_s)
+            except Exception as e:
+                # the epoch's dirt was requeued; stop here and let the next
+                # query raise it rather than serve an ever-staler bank
+                self.failure = e
+                return

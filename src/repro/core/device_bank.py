@@ -77,12 +77,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.collectives import topk_allgather_merge
-from repro.kernels.retrieval_topk.ops import (default_int4_impl,
-                                              retrieval_topk,
+from repro.kernels.retrieval_topk.kernel import (
+    retrieval_topk_int4_gathered_pallas, retrieval_topk_int4_pallas)
+from repro.kernels.retrieval_topk.ops import (resolve_impl, retrieval_topk,
                                               retrieval_topk_int4,
                                               retrieval_topk_int4_gathered,
                                               retrieval_topk_int4_rows)
@@ -120,11 +120,17 @@ class DeviceBank:
     ``EmbeddingStore``; ``store_int4=False`` mirrors fp32 rows (debug mode)
     and searches them with the dense kernel instead of the fused dequant
     scan. See module docstring for the refresh protocol.
+
+    ``impl``/``interpret`` are resolved once, here, from the platform of
+    ``devices`` (``ops.resolve_impl``): on a TPU every scan of this bank
+    runs the compiled Pallas kernel, and ``(bank.impl, bank.interpret)``
+    names what served it.
     """
 
     def __init__(self, embed_dim: int, *, store_int4: bool = True,
                  devices: Optional[Sequence[jax.Device]] = None,
-                 impl: str = "auto", block_n: int = 4096):
+                 impl: str = "auto", interpret: Optional[bool] = None,
+                 block_n: int = 4096):
         self.embed_dim = embed_dim
         self.store_int4 = store_int4
         devs = list(devices) if devices is not None else list(jax.devices())
@@ -134,7 +140,8 @@ class DeviceBank:
         self._sh_rows = NamedSharding(self.mesh, P("bank"))
         self._row_width = embed_dim // 2 if store_int4 else embed_dim
         self._row_dtype = jnp.int8 if store_int4 else jnp.float32
-        self.impl = impl
+        self.impl, self.interpret = resolve_impl(
+            impl, interpret, int4=store_int4, platform=devs[0].platform)
         self.block_n = block_n
         self._cap = 0
         # the published BankSnapshot, swapped as ONE object: a reader
@@ -325,7 +332,7 @@ class DeviceBank:
                 warm_retrieval_topk_int4)
             warm_retrieval_topk_int4(
                 (nq, self.embed_dim), tuple(state.packed.shape), k,
-                normalize=False, impl=self._resolve_impl(),
+                normalize=False, impl=self.impl, interpret=self.interpret,
                 **dict({"block_n": self.block_n}, **dict(kw)))
         else:
             dummy = np.zeros((nq, self.embed_dim), np.float32)
@@ -353,13 +360,6 @@ class DeviceBank:
 
     # -- search --------------------------------------------------------------
 
-    def _resolve_impl(self) -> str:
-        if self.impl != "auto":
-            return self.impl
-        if self.store_int4:
-            return default_int4_impl()
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-
     def _sharded_search_fn(self, k: int, impl: str, cap: int):
         """Jitted shard_map search for a snapshot's capacity: per-shard
         fused top-k over the local rows, one small all-gather merge."""
@@ -371,15 +371,13 @@ class DeviceBank:
         k_loc = min(k, rps)
         int4 = self.store_int4
         block_n = self.block_n
-        interpret = jax.default_backend() != "tpu"
+        interpret = self.interpret
 
         def local(q, p, sc, n):
             sid = jax.lax.axis_index("bank")
             n_loc = jnp.clip(n - sid * rps, 0, rps).astype(jnp.int32)
             if int4:
                 if impl == "pallas":
-                    from repro.kernels.retrieval_topk.kernel import (
-                        retrieval_topk_int4_pallas)
                     s, i = retrieval_topk_int4_pallas(
                         q, p, sc, k_loc, normalize=False, n_valid=n_loc,
                         interpret=interpret)
@@ -396,10 +394,10 @@ class DeviceBank:
         mesh = self.mesh
 
         def search(q, p, sc, n):
-            return shard_map(local, mesh=mesh,
-                             in_specs=(P(), P("bank"), P("bank"), P()),
-                             out_specs=(P(), P()), check_rep=False)(
-                                 q, p, sc, n)
+            return jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P("bank"), P("bank"), P()),
+                                 out_specs=(P(), P()), check_vma=False)(
+                                     q, p, sc, n)
 
         fn = jax.jit(search)
         self._search_fns[key] = fn
@@ -425,17 +423,19 @@ class DeviceBank:
         packed, scales, n = state.packed, state.scales, state.n
         k = min(k, n)
         q = jnp.asarray(np.asarray(queries, np.float32))
-        impl = self._resolve_impl()
+        impl = self.impl
         if self.n_shards == 1:
             if self.store_int4:
                 s, i = retrieval_topk_int4(q, packed, scales, k,
                                            normalize=False, impl=impl,
+                                           interpret=self.interpret,
                                            n_valid=n,
                                            **dict({"block_n": self.block_n},
                                                   **kw))
             else:
                 s, i = retrieval_topk(q, packed, k, normalize=False,
-                                      impl=impl, n_valid=n, **kw)
+                                      impl=impl, interpret=self.interpret,
+                                      n_valid=n, **kw)
         else:
             if kw:
                 raise ValueError("sharded DeviceBank.search takes no kernel "
@@ -461,7 +461,7 @@ class DeviceBank:
             return fn
         rps = cap // self.n_shards
         block_n = self.block_n
-        interpret = jax.default_backend() != "tpu"
+        interpret = self.interpret
 
         def local(q, p, sc, rows, m):
             sid = jax.lax.axis_index("bank")
@@ -470,8 +470,6 @@ class DeviceBank:
             gp = jnp.take(p, rloc, axis=0)        # (M, E//2) int4 bytes
             gs = jnp.take(sc, rloc, axis=0)       # (M, 1)
             if impl == "pallas":
-                from repro.kernels.retrieval_topk.kernel import (
-                    retrieval_topk_int4_pallas)
                 s, i = retrieval_topk_int4_pallas(
                     q, gp, gs, k_loc, normalize=False, n_valid=mloc,
                     interpret=interpret)
@@ -488,11 +486,11 @@ class DeviceBank:
         mesh = self.mesh
 
         def search(q, p, sc, rows, m):
-            return shard_map(local, mesh=mesh,
-                             in_specs=(P(), P("bank"), P("bank"), P("bank"),
-                                       P("bank")),
-                             out_specs=(P(), P()), check_rep=False)(
-                                 q, p, sc, rows, m)
+            return jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P("bank"), P("bank"),
+                                           P("bank"), P("bank")),
+                                 out_specs=(P(), P()), check_vma=False)(
+                                     q, p, sc, rows, m)
 
         fn = jax.jit(search)
         self._search_fns[key] = fn
@@ -511,7 +509,7 @@ class DeviceBank:
         if fn is not None:
             return fn
         rps = cap // self.n_shards
-        interpret = jax.default_backend() != "tpu"
+        interpret = self.interpret
 
         def local(q, p, sc, ids, n):
             sid = jax.lax.axis_index("bank")
@@ -520,8 +518,6 @@ class DeviceBank:
             lid = ids - base
             lid = jnp.where((ids >= 0) & (lid >= 0) & (lid < rps), lid, -1)
             if impl == "pallas":
-                from repro.kernels.retrieval_topk.kernel import (
-                    retrieval_topk_int4_gathered_pallas)
                 safe = jnp.clip(lid, 0, rps - 1)
                 gp = jnp.take(p, safe, axis=0)    # (Q, L, E//2) int4 bytes
                 gs = jnp.take(sc, safe, axis=0)   # (Q, L, 1)
@@ -536,10 +532,11 @@ class DeviceBank:
         mesh = self.mesh
 
         def search(q, p, sc, ids, n):
-            return shard_map(local, mesh=mesh,
-                             in_specs=(P(), P("bank"), P("bank"), P(), P()),
-                             out_specs=(P(), P()), check_rep=False)(
-                                 q, p, sc, ids, n)
+            return jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P("bank"), P("bank"), P(),
+                                           P()),
+                                 out_specs=(P(), P()), check_vma=False)(
+                                     q, p, sc, ids, n)
 
         fn = jax.jit(search)
         self._search_fns[key] = fn
@@ -571,7 +568,8 @@ class DeviceBank:
         if self.n_shards == 1:
             s, i = retrieval_topk_int4_gathered(
                 q, state.packed, state.scales, row_ids, k, normalize=False,
-                impl=self._resolve_impl(), n_valid=state.n, **kw)
+                impl=self.impl, interpret=self.interpret, n_valid=state.n,
+                **kw)
             return np.asarray(i, np.int64), np.asarray(s, np.float32)
         if kw:
             raise ValueError("sharded DeviceBank.search_gathered takes no "
@@ -580,7 +578,7 @@ class DeviceBank:
         if row_ids.shape[1] < k:  # top-k needs >= k columns (-1 = masked)
             row_ids = np.pad(row_ids, ((0, 0), (0, k - row_ids.shape[1])),
                              constant_values=-1)
-        fn = self._sharded_gathered_fn(k, self._resolve_impl(),
+        fn = self._sharded_gathered_fn(k, self.impl,
                                        state.packed.shape[0],
                                        row_ids.shape[1])
         s, i = fn(q, state.packed, state.scales, jnp.asarray(row_ids),
@@ -613,7 +611,7 @@ class DeviceBank:
         if self.n_shards == 1:
             s, i = retrieval_topk_int4_rows(
                 q, state.packed, state.scales, rows, k, normalize=False,
-                impl=self._resolve_impl(), **kw)
+                impl=self.impl, interpret=self.interpret, **kw)
             rows = np.asarray(rows, np.int64)
             return rows[np.asarray(i, np.int64)], np.asarray(s, np.float32)
         if kw:
@@ -625,7 +623,7 @@ class DeviceBank:
         local, counts = partition_rows_by_shard(rows, cap // self.n_shards,
                                                 self.n_shards)
         k_loc = min(k, local.shape[1])
-        fn = self._sharded_rows_fn(k, k_loc, self._resolve_impl(), cap,
+        fn = self._sharded_rows_fn(k, k_loc, self.impl, cap,
                                    local.shape[1])
         s, gids = fn(q, state.packed, state.scales, jnp.asarray(local),
                      jnp.asarray(counts))

@@ -27,7 +27,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quantize import dequantize_int8, quantize_int8
@@ -119,9 +118,9 @@ def flash_decode_seqparallel(mesh: Mesh, axis: str):
             B, KV, G, D = out.shape
             return out.reshape(B, KV * G, D).astype(q.dtype)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), P()),
-            out_specs=P(), check_rep=False)(q, k, v, lengths)
+            out_specs=P(), check_vma=False)(q, k, v, lengths)
 
     return fn

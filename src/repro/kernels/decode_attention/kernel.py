@@ -16,13 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -95,9 +89,9 @@ def decode_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j, lens: (b, h, 0, 0)),
         scratch_shapes=[
-            _VMEM((G, D), jnp.float32),
-            _VMEM((G, 1), jnp.float32),
-            _VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -18,12 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific helpers are importable on CPU builds of jax
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -108,9 +103,9 @@ def flash_fwd_pallas(cfg, q, k, v, *, interpret: bool = True
             jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            _VMEM((bq, D), jnp.float32),
-            _VMEM((bq, 1), jnp.float32),
-            _VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qf, k.reshape(B, KV, Skv, D), v.reshape(B, KV, Skv, D))

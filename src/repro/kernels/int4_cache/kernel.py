@@ -14,13 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 
 def _quant_kernel(x_ref, packed_ref, scale_ref):
     x = x_ref[...].astype(jnp.float32)  # (bn, D)

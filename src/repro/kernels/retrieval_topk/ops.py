@@ -1,16 +1,20 @@
 """Dispatch wrappers for fused retrieval top-k.
 
 ``retrieval_topk`` scans a dense fp32 bank; ``impl`` selects the backend:
-  * ``"auto"`` (default) — Pallas kernel when importable (interpret mode on
-    CPU, compiled on TPU), else the jnp/XLA reference.
-  * ``"pallas"`` — force the Pallas kernel; ``interpret=None`` auto-detects
-    (interpret off only on TPU).
+  * ``"auto"`` (default) — the Pallas kernel on TPU (compiled) and CPU
+    (interpreted), the jnp/XLA reference elsewhere.
+  * ``"pallas"`` — force the Pallas kernel.
   * ``"xla"`` — force the jnp reference (normalize → matmul → lax.top_k).
 
 ``retrieval_topk_int4`` scans a *packed int4* bank (the device-resident
 DeviceBank path) with in-flight dequantization — the fp32 bank never
 materializes: ``"pallas"`` dequantizes in VMEM, ``"xla"`` is a blocked jnp
 scan compiled everywhere, ``"ref"`` the dequant-all oracle.
+
+``resolve_impl`` is the one place that turns ``impl="auto"`` and
+``interpret=None`` into a concrete (backend, interpret mode) pair; every
+entry below and ``DeviceBank`` go through it, and the kernels themselves
+take ``interpret`` as a required argument.
 """
 from __future__ import annotations
 
@@ -21,37 +25,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.retrieval_topk.kernel import (
+    retrieval_topk_int4_gathered_pallas, retrieval_topk_int4_pallas,
+    retrieval_topk_pallas)
 from repro.kernels.retrieval_topk.ref import (
     retrieval_topk_int4_blocked, retrieval_topk_int4_gathered_blocked,
     retrieval_topk_int4_gathered_reference, retrieval_topk_int4_reference,
     retrieval_topk_reference)
 
-try:
-    from repro.kernels.retrieval_topk import kernel as _kernel
-    retrieval_topk_pallas = _kernel.retrieval_topk_pallas
-    retrieval_topk_int4_pallas = _kernel.retrieval_topk_int4_pallas
-    retrieval_topk_int4_gathered_pallas = \
-        _kernel.retrieval_topk_int4_gathered_pallas
-    # kernel.py imports with _VMEM=None when pallas.tpu is missing; the
-    # pallas_call scratch_shapes would then crash, so treat it as absent
-    _HAS_PALLAS = _kernel._VMEM is not None
-except Exception:  # pragma: no cover — pallas not in this jax build
-    retrieval_topk_pallas = None
-    retrieval_topk_int4_pallas = None
-    retrieval_topk_int4_gathered_pallas = None
-    _HAS_PALLAS = False
 
-
-def default_impl() -> str:
-    if not _HAS_PALLAS:
-        return "xla"
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return "pallas"          # compiled Mosaic kernel
-    if backend == "cpu":
-        return "pallas"          # interpret mode (correctness/testing path)
-    return "xla"  # GPU: the TPU kernel can't compile there and interpret
-    #               mode would crawl — the compiled reference wins
+def resolve_impl(impl: Optional[str] = "auto",
+                 interpret: Optional[bool] = None, *, int4: bool = True,
+                 platform: Optional[str] = None
+                 ) -> Tuple[str, Optional[bool]]:
+    """Concrete (impl, interpret) for a scan on ``platform`` (default: the
+    default backend). ``auto`` takes the compiled Pallas kernel on a TPU.
+    Elsewhere the int4 scans take the blocked XLA scan (the interpreted
+    kernel loses to it) and the dense scan the interpreted kernel (its
+    correctness path). ``interpret`` is None for non-Pallas impls; for
+    Pallas an explicit value wins, else the kernel is interpreted exactly
+    when the platform is not a TPU."""
+    platform = platform or jax.default_backend()
+    if impl in (None, "auto"):
+        if platform == "tpu" or (platform == "cpu" and not int4):
+            impl = "pallas"
+        else:
+            impl = "xla"
+    allowed = ("pallas", "xla", "ref") if int4 else ("pallas", "xla")
+    if impl not in allowed:
+        raise ValueError(f"unknown retrieval_topk impl: {impl!r}")
+    if impl != "pallas":
+        return impl, None
+    return impl, (platform != "tpu") if interpret is None else bool(interpret)
 
 
 @functools.lru_cache(maxsize=128)
@@ -78,18 +83,9 @@ def retrieval_topk(query: jax.Array, bank: jax.Array, k: int, *,
                    **kw) -> Tuple[jax.Array, jax.Array]:
     """``n_valid`` restricts the scan to the first n_valid bank rows (for
     capacity-padded slabs); defaults to the whole bank."""
-    if impl in (None, "auto"):
-        impl = default_impl()
+    impl, interpret = resolve_impl(impl, interpret, int4=False)
     if impl == "pallas":
-        if not _HAS_PALLAS:
-            raise RuntimeError("retrieval_topk impl='pallas' requested but "
-                               "the Pallas kernel is unavailable in this jax "
-                               "build; use impl='auto' or 'xla'")
-        if interpret is None:  # resolve here so the jit cache key is concrete
-            interpret = jax.default_backend() != "tpu"
         kw = dict(kw, interpret=interpret)
-    elif impl != "xla":
-        raise ValueError(f"unknown retrieval_topk impl: {impl!r}")
     # both backends take the valid-row count as a traced scalar so a
     # capacity-padded bank reuses one compilation across fill levels
     n_arr = jnp.asarray(bank.shape[0] if n_valid is None else n_valid,
@@ -103,14 +99,6 @@ def retrieval_topk(query: jax.Array, bank: jax.Array, k: int, *,
 # ---------------------------------------------------------------------------
 
 
-def default_int4_impl() -> str:
-    backend = jax.default_backend()
-    if backend == "tpu" and _HAS_PALLAS:
-        return "pallas"      # in-VMEM dequant, int4 HBM traffic
-    return "xla"             # blocked jnp scan compiles everywhere and never
-    #                          materializes the fp32 bank (see ref.py)
-
-
 # ahead-of-time compiled executables, keyed by (dispatch key, arg shapes).
 # Populated by ``warm_retrieval_topk_int4`` (the async bank refresher calls
 # it for a grown bank BEFORE publishing, so the retrace+compile never lands
@@ -118,19 +106,10 @@ def default_int4_impl() -> str:
 _AOT_INT4 = {}
 
 
-def _int4_dispatch_key(impl, interpret, k, normalize, kw):
-    if impl in (None, "auto"):
-        impl = default_int4_impl()
+def _int4_dispatch_key(impl, interpret, kw):
+    impl, interpret = resolve_impl(impl, interpret)
     if impl == "pallas":
-        if not _HAS_PALLAS:
-            raise RuntimeError("retrieval_topk_int4 impl='pallas' requested "
-                               "but the Pallas kernel is unavailable in this "
-                               "jax build; use impl='auto' or 'xla'")
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         kw = dict(kw, interpret=interpret)
-    elif impl not in ("xla", "ref"):
-        raise ValueError(f"unknown retrieval_topk_int4 impl: {impl!r}")
     return impl, tuple(sorted(kw.items()))
 
 
@@ -143,7 +122,7 @@ def warm_retrieval_topk_int4(query_shape: Tuple[int, int],
     cache, so the executable is parked in a side table the dispatch checks
     first). Compilation costs 10-20x a steady scan; doing it off the query
     path is the point — see ``DeviceBank.warm``."""
-    impl, kwt = _int4_dispatch_key(impl, interpret, k, normalize, kw)
+    impl, kwt = _int4_dispatch_key(impl, interpret, kw)
     key = (impl, k, normalize, kwt, tuple(query_shape), tuple(packed_shape))
     if key in _AOT_INT4:
         return
@@ -188,7 +167,7 @@ def retrieval_topk_int4(query: jax.Array, packed: jax.Array,
     fp32 bank is never materialized: rows dequantize block-wise right before
     scoring. ``impl``: 'pallas' (TPU kernel / interpret), 'xla' (blocked jnp
     scan, compiled everywhere), 'ref' (dequant-all oracle), or 'auto'."""
-    impl, kwt = _int4_dispatch_key(impl, interpret, k, normalize, kw)
+    impl, kwt = _int4_dispatch_key(impl, interpret, kw)
     n_arr = jnp.asarray(packed.shape[0] if n_valid is None else n_valid,
                         jnp.int32)
     aot = _AOT_INT4.get((impl, k, normalize, kwt, tuple(query.shape),
@@ -247,7 +226,7 @@ def retrieval_topk_int4_gathered(query: jax.Array, packed: jax.Array,
     slots with no live candidate score -1e30 (callers map them to uid -1).
     The ``normalize`` flag is honored by the xla/ref paths only (the store
     scans with raw inner products everywhere)."""
-    impl, kwt = _int4_dispatch_key(impl, interpret, k, normalize, kw)
+    impl, kwt = _int4_dispatch_key(impl, interpret, kw)
     if impl == "pallas" and normalize:
         raise ValueError("gathered pallas path scans raw inner products; "
                          "normalize=True is only supported on impl='xla'/"
@@ -321,7 +300,7 @@ def retrieval_topk_int4_rows(query: jax.Array, packed: jax.Array,
     gathered inside the jit. Returns ((Q, k) scores, (Q, k) LOCAL indices
     into ``rows``) — callers map back via ``rows[ids]``. Requires
     ``k <= len(rows)``."""
-    impl, kwt = _int4_dispatch_key(impl, interpret, k, normalize, kw)
+    impl, kwt = _int4_dispatch_key(impl, interpret, kw)
     rows = np.asarray(rows, np.int32).ravel()
     m = rows.size
     assert 0 < k <= m, (k, m)

@@ -8,17 +8,21 @@ the DCN/inter-pod dimension; data parallelism spans (pod, data).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / elastic recovery)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests / elastic recovery). Axes are ``Auto``: the
+    steps place arrays with ``with_sharding_constraint`` and let XLA
+    partition, which ``jax.make_mesh``'s default ``Explicit`` axes refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e constants used by the roofline analysis (per chip).

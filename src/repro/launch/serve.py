@@ -10,7 +10,9 @@ Smoke-scale on CPU:
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,20 @@ from repro.serving.engine import EmbeddingEngine
 from repro.serving.query import QueryEngine
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process; call it
+    from an entry point's ``main()`` before anything compiles. A set
+    ``JAX_COMPILATION_CACHE_DIR`` is used as it is (JAX reads it itself);
+    otherwise the cache lives at ``<checkout>/.jax_cache``, a fixed path,
+    since the path is part of what a later run must find again. Returns
+    the directory in use."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def build_service(spec, *, n_train: int = 256, seed: int = 0, policy="recall",
                   params=None, lora=None, fw_kw=None, search_impl="auto",
                   search_devices=None, bank_refresh="sync",
@@ -37,21 +53,24 @@ def build_service(spec, *, n_train: int = 256, seed: int = 0, policy="recall",
     cfg, recall = spec.model, spec.recall
     key = jax.random.PRNGKey(seed)
     if params is None:
-        params = IB.mem_init(key, cfg, recall)
+        # one compiled init instead of an eager compile per weight shape
+        params = jax.jit(IB.mem_init, static_argnums=(1, 2))(key, cfg, recall)
     fw_kw = fw_kw or {}
     data = SYN.multimodal_pairs(seed, n_train, cfg)
     vis = jnp.asarray(data.items["vision"])
 
-    # self-supervised exit labels on a calibration split
-    all_exits = IB.mem_embed_all_exits(params, cfg, recall, "vision", vis,
-                                       lora=lora, **fw_kw)
-    labels = EX.optimal_exit_labels(all_exits["exit_embs"],
-                                    all_exits["exit_embs"][-1])
-    sup = IB.tower_forward(params, cfg, recall, "vision", vis,
-                           layer_end=recall.superficial_layers, lora=lora,
-                           **fw_kw)["pooled"][-1]
+    # self-supervised exit labels on a calibration split (jitted with the
+    # weights as arguments: eager, every op compiles on its own)
+    exit_embs = jax.jit(lambda p, lo, x: IB.mem_embed_all_exits(
+        p, cfg, recall, "vision", x, lora=lo, **fw_kw)["exit_embs"])(
+            params, lora, vis)
+    labels = EX.optimal_exit_labels(exit_embs, exit_embs[-1])
+    sup = jax.jit(lambda p, lo, x: IB.tower_forward(
+        p, cfg, recall, "vision", x, layer_end=recall.superficial_layers,
+        lora=lo, **fw_kw)["pooled"][-1])(params, lora, vis)
     predictor, stats = PE.train_predictor(
-        key, sup, labels, n_exits=len(all_exits["exits"]),
+        key, sup, labels, n_exits=len(recall.exit_layers(
+            cfg.tower("vision").n_layers)),
         hidden=recall.predictor_hidden, steps=150)
 
     store = EmbeddingStore(cfg.embed_dim)
@@ -129,6 +148,7 @@ def main():
                          "--index-clusters choice (keeps the probed "
                          "fraction sub-linear as the store scales)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     if args.smoke:

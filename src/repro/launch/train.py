@@ -31,6 +31,7 @@ from repro.data import synthetic as SYN
 from repro.data.pipeline import ShardedLoader
 from repro.distributed.mesh_utils import sharding_ctx
 from repro.distributed.straggler import Action, StragglerMonitor
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_step
 
 
@@ -55,7 +56,7 @@ def train_loop(spec, shape, *, mesh=None, multi_pod: bool = False,
                seed: int = 0) -> Dict[str, Any]:
     """Build, (maybe) restore, and run the train step for `steps` steps."""
     if mesh is None:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
     shape_cfg = spec.shape(shape) if isinstance(shape, str) else shape
     bundle = build_step(spec, shape_cfg, mesh, multi_pod=multi_pod)
 

@@ -33,6 +33,9 @@ class ParamDef(NamedTuple):
     axes: Tuple[Optional[str], ...]  # logical axis names, len == len(shape)
     init: str = "normal"  # normal | zeros | ones | embed | fan_in
     scale: float = 1.0
+    # "fan_in" init: the contracted input size; 0 = shape[-2], right for a
+    # plain (..., in, out) matrix but not for head-split attention weights
+    fan_in: int = 0
 
 
 Schema = Dict[str, Any]  # nested dict of ParamDef
@@ -48,7 +51,8 @@ def _init_leaf(key: jax.Array, d: ParamDef, dtype) -> jax.Array:
     if d.init == "embed":
         return (d.scale * jax.random.normal(key, d.shape) * 0.02).astype(dtype)
     if d.init == "fan_in":
-        fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
+        fan_in = d.fan_in or (d.shape[-2] if len(d.shape) >= 2
+                              else d.shape[-1])
         std = d.scale / np.sqrt(fan_in)
         return (std * jax.random.normal(key, d.shape)).astype(dtype)
     raise ValueError(d.init)
@@ -180,10 +184,10 @@ def attn_schema(d_model: int, n_heads: int, n_kv: int, head_dim: int,
     L = layer_dims
     la = tuple("layer" for _ in L)
     s: Schema = {
-        "wq": ParamDef(L + (d_model, n_heads, head_dim), la + ("embed", "heads", "head_dim"), "fan_in"),
-        "wk": ParamDef(L + (d_model, n_kv, head_dim), la + ("embed", "kv_heads", "head_dim"), "fan_in"),
-        "wv": ParamDef(L + (d_model, n_kv, head_dim), la + ("embed", "kv_heads", "head_dim"), "fan_in"),
-        "wo": ParamDef(L + (n_heads, head_dim, d_model), la + ("heads", "head_dim", "embed"), "fan_in"),
+        "wq": ParamDef(L + (d_model, n_heads, head_dim), la + ("embed", "heads", "head_dim"), "fan_in", fan_in=d_model),
+        "wk": ParamDef(L + (d_model, n_kv, head_dim), la + ("embed", "kv_heads", "head_dim"), "fan_in", fan_in=d_model),
+        "wv": ParamDef(L + (d_model, n_kv, head_dim), la + ("embed", "kv_heads", "head_dim"), "fan_in", fan_in=d_model),
+        "wo": ParamDef(L + (n_heads, head_dim, d_model), la + ("heads", "head_dim", "embed"), "fan_in", fan_in=n_heads * head_dim),
     }
     if qkv_bias:
         s["bq"] = ParamDef(L + (n_heads, head_dim), la + ("heads", "head_dim"), "zeros")

@@ -68,30 +68,35 @@ class EmbeddingEngine:
         self.stats = EngineStats()
         self._queue: List[Tuple[int, np.ndarray]] = []
 
+        # every jitted model fn takes (params, lora) as arguments: closed
+        # over, the weights would be baked into each executable as constants
+        # (GBs per compile at published widths)
         self._jit_superficial = jax.jit(self._superficial)
         self._jit_continue = {}  # (start, end) -> jitted fn
 
     # -- model fns -------------------------------------------------------------
 
-    def _superficial(self, x):
+    def _superficial(self, params, lora, x):
         """First-N-layer pass; returns hidden state + per-layer pooled states
         (exits at depth <= N read their embedding straight from these)."""
         N = self.recall.superficial_layers
-        out = IB.tower_forward(self.params, self.cfg, self.recall, self.modality,
-                               x, layer_end=N, lora=self.lora, **self.fw_kw)
+        out = IB.tower_forward(params, self.cfg, self.recall, self.modality,
+                               x, layer_end=N, lora=lora, **self.fw_kw)
         return out["h"], out["pooled"]  # (B,S,d), (N,B,d)
 
     def _continue_fn(self, start: int, end: int):
+        """Jitted ``fn(params, lora, h)``: layers [start, end) from a
+        cached hidden state, then the exit head."""
         key = (start, end)
         if key not in self._jit_continue:
-            def fn(h):
-                out = IB.tower_forward(self.params, self.cfg, self.recall,
+            def fn(params, lora, h):
+                out = IB.tower_forward(params, self.cfg, self.recall,
                                        self.modality, inputs=None, h_state=h,
                                        layer_start=start, layer_end=end,
-                                       lora=self.lora, **self.fw_kw)
-                tp = self.params["towers"][self.modality]
-                emb = T.exit_embedding(tp, out["pooled"][-1], self.cfg.norm_eps)
-                return emb
+                                       lora=lora, **self.fw_kw)
+                tp = params["towers"][self.modality]
+                return T.exit_embedding(tp, out["pooled"][-1],
+                                        self.cfg.norm_eps)
             self._jit_continue[key] = jax.jit(fn)
         return self._jit_continue[key]
 
@@ -130,7 +135,9 @@ class EmbeddingEngine:
         # hidden states; branchynet also starts from layer 0 per sample.
         h_sup_parts, pooled_parts = [], []
         for i in range(0, len(items), self.max_batch):
-            h, pooled = self._jit_superficial(jnp.asarray(items[i:i + self.max_batch]))
+            h, pooled = self._jit_superficial(
+                self.params, self.lora,
+                jnp.asarray(items[i:i + self.max_batch]))
             h_sup_parts.append(np.asarray(h))
             pooled_parts.append(np.asarray(pooled))
             self.stats.superficial_batches += 1
@@ -160,7 +167,8 @@ class EmbeddingEngine:
                 layers_run = N  # superficial pass was still paid
             else:
                 fn = self._continue_fn(N, exit_layer)
-                embs = np.asarray(fn(jnp.asarray(h_sup[ids])))
+                embs = np.asarray(fn(self.params, self.lora,
+                                     jnp.asarray(h_sup[ids])))
                 layers_run = exit_layer
             self.stats.group_batches += 1
             self.stats.layers_executed += float(len(ids) * layers_run)
@@ -180,12 +188,13 @@ class EmbeddingEngine:
 
     def _branchynet_exits(self, items: np.ndarray, tau: float = 0.95) -> np.ndarray:
         """Per-sample confidence exits (baseline; no batching by design)."""
-        fn = jax.jit(lambda x: IB.mem_embed_all_exits(
-            self.params, self.cfg, self.recall, self.modality, x,
-            lora=self.lora, **self.fw_kw)["exit_embs"])
+        fn = jax.jit(lambda p, lo, x: IB.mem_embed_all_exits(
+            p, self.cfg, self.recall, self.modality, x, lora=lo,
+            **self.fw_kw)["exit_embs"])
         out = np.zeros(len(items), np.int64)
         for i in range(len(items)):
-            embs = np.asarray(fn(jnp.asarray(items[i:i + 1])))[:, 0]  # (n_exits, E)
+            embs = np.asarray(fn(self.params, self.lora,
+                                 jnp.asarray(items[i:i + 1])))[:, 0]  # (n_exits, E)
             exit_i = len(self.exits) - 1
             for e in range(len(self.exits) - 1):
                 if float(embs[e] @ embs[e + 1]) > tau:
@@ -224,7 +233,8 @@ class EmbeddingEngine:
                 for i in range(0, len(us), self.max_batch):
                     chunk = us[i:i + self.max_batch]
                     h = np.stack([cached[u][0] for u in chunk])
-                    embs = np.asarray(fn(jnp.asarray(h)))
+                    embs = np.asarray(fn(self.params, self.lora,
+                                         jnp.asarray(h)))
                     out.update(zip(chunk, embs))
             if scalar:
                 return out.get(int(uids))

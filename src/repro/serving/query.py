@@ -107,9 +107,11 @@ class QueryEngine:
         # spread query granularities across the exit range (incl. full depth)
         idx = np.unique(np.linspace(0, len(exits) - 1, k).round().astype(int))
         self.granularities = [exits[i] for i in idx]
-        self._jit_all_exits = jax.jit(lambda x: IB.mem_embed_all_exits(
-            self.params, self.cfg, self.recall, self.modality, x,
-            lora=self.lora, **self.fw_kw)["exit_embs"])
+        # weights as arguments, not closed over: a closure would bake them
+        # into the executable as constants
+        self._jit_all_exits = jax.jit(lambda p, lo, x: IB.mem_embed_all_exits(
+            p, self.cfg, self.recall, self.modality, x, lora=lo,
+            **self.fw_kw)["exit_embs"])
         self._exits = exits
         self._g_rows = [exits.index(g) for g in self.granularities]
 
@@ -117,13 +119,15 @@ class QueryEngine:
 
     def embed_query(self, query: np.ndarray) -> Dict[int, np.ndarray]:
         """One tower pass gives every granularity (exit taps are free)."""
-        embs = np.asarray(self._jit_all_exits(jnp.asarray(query[None])))[:, 0]
+        embs = np.asarray(self._jit_all_exits(
+            self.params, self.lora, jnp.asarray(query[None])))[:, 0]
         return {e: embs[self._exits.index(e)] for e in self.granularities}
 
     def embed_query_batch(self, queries: np.ndarray) -> np.ndarray:
         """(B, ...) query batch -> (B, G, E) granularity embeddings from ONE
         tower pass (row -1 is the fine/full-depth embedding)."""
-        embs = np.asarray(self._jit_all_exits(jnp.asarray(queries)))
+        embs = np.asarray(self._jit_all_exits(self.params, self.lora,
+                                              jnp.asarray(queries)))
         return embs[self._g_rows].transpose(1, 0, 2)  # (B, G, E)
 
     # -- single query --------------------------------------------------------

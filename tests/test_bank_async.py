@@ -209,6 +209,30 @@ def test_apply_failure_requeues_dirty_rows():
         assert set(a.tolist()) == set(b.tolist())
 
 
+def test_background_epoch_failure_surfaces_on_the_next_query():
+    """A failed epoch on the refresh thread stops the thread and is raised
+    by the next query, instead of the bank silently falling behind."""
+    st = _store_with_rows()
+    q = _embs(2, seed=3)
+    st.search_batch(q, 5, impl="device")             # publish generation 1
+    ref = st.set_bank_refresh("async", max_lag_rows=0)
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected device failure")
+
+    st.device_bank.apply_rows = boom
+    st.upgrade_batch([3, 4], _embs(2, seed=16))
+    st.kick_bank_refresh()
+    ref._thread.join(timeout=10)
+    assert not ref._thread.is_alive()
+    assert isinstance(ref.failure, RuntimeError)
+    assert ref.lag()[0] == 2                          # dirt was requeued
+    with pytest.raises(RuntimeError, match="background bank refresh"):
+        st.search_batch(q, 5, impl="device")
+    del st.device_bank.apply_rows                     # device recovers
+    st.set_bank_refresh("sync")                       # drain publishes it
+
+
 def test_stale_snapshot_with_deleted_uid_does_not_crash_retrieval():
     """A lagging snapshot can surface a uid deleted since its generation;
     the retrieval pipeline must drop it before the live-embedding rounds

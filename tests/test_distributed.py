@@ -29,6 +29,7 @@ def test_sharded_lm_loss_matches_single_device():
         from repro.models import transformer as T
         from repro.distributed import mesh_utils
         from repro.distributed.mesh_utils import sharding_ctx
+        from repro.launch.mesh import make_mesh
 
         cfg = LMConfig(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
                        d_ff=64, vocab=64, d_head=8, dtype="float32")
@@ -39,7 +40,7 @@ def test_sharded_lm_loss_matches_single_device():
         fw = dict(block_q=8, block_kv=8, chunk=8)
         ref = float(T.lm_loss(params, cfg, rc, toks, labels, **fw)[0])
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = mesh_utils.lm_rules(False)
         p_sh = mesh_utils.make_shardings(T.lm_specs(cfg, rc, embed_out=16),
                                          mesh, rules,
@@ -57,7 +58,7 @@ def test_sharded_lm_loss_matches_single_device():
 def test_compressed_psum_close_to_exact():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum
 
@@ -138,10 +139,11 @@ def test_tiny_mesh_dryrun_cell():
         from repro.launch.steps import build_step
         from repro.launch import hlo_analysis as H
         from repro.distributed.mesh_utils import sharding_ctx
+        from repro.launch.mesh import make_mesh
 
         spec = smoke_variant(get_arch("qwen2-1.5b"))
         shape = spec.shapes[0]
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         bundle = build_step(spec, shape, mesh)
         jitted = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                          out_shardings=bundle.out_shardings,

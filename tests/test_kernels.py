@@ -15,6 +15,7 @@ from repro.kernels.int4_cache.kernel import (dequantize_int4_pallas,
 from repro.kernels.moe_gemm.ops import moe_gemm, sort_by_expert
 from repro.kernels.moe_gemm.ref import moe_gemm_reference
 from repro.kernels.retrieval_topk.kernel import retrieval_topk_pallas
+from repro.kernels.retrieval_topk.ops import resolve_impl
 from repro.kernels.retrieval_topk.ref import retrieval_topk_reference
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.models.layers import rmsnorm
@@ -119,7 +120,8 @@ def test_topk_pallas_vs_ref(Q, N, E, k, bq, bn):
     q = jax.random.normal(jax.random.PRNGKey(1), (Q, E))
     bank = jax.random.normal(jax.random.PRNGKey(2), (N, E))
     sr, ir = retrieval_topk_reference(q, bank, k)
-    sp, ip = retrieval_topk_pallas(q, bank, k, block_q=bq, block_n=bn)
+    sp, ip = retrieval_topk_pallas(q, bank, k, block_q=bq, block_n=bn,
+                                   interpret=True)
     np.testing.assert_allclose(sr, sp, atol=1e-5)
     # ids compared as sets per row (ties may permute)
     for r in range(Q):
@@ -131,9 +133,31 @@ def test_topk_unnormalized():
     bank = jax.random.normal(jax.random.PRNGKey(4), (64, 8))
     sr, ir = retrieval_topk_reference(q, bank, 4, normalize=False)
     sp, ip = retrieval_topk_pallas(q, bank, 4, normalize=False, block_q=4,
-                                   block_n=16)
+                                   block_n=16, interpret=True)
     np.testing.assert_allclose(sr, sp, atol=1e-5)
     np.testing.assert_array_equal(ir, ip)
+
+
+@pytest.mark.parametrize("impl,interpret,int4,platform,want", [
+    ("auto", None, True, "tpu", ("pallas", False)),
+    ("auto", None, False, "tpu", ("pallas", False)),
+    ("auto", None, True, "cpu", ("xla", None)),
+    ("auto", None, False, "cpu", ("pallas", True)),
+    ("auto", None, True, "gpu", ("xla", None)),
+    ("pallas", None, True, "cpu", ("pallas", True)),
+    ("pallas", False, True, "cpu", ("pallas", False)),
+    ("ref", True, True, "tpu", ("ref", None)),
+])
+def test_resolve_impl(impl, interpret, int4, platform, want):
+    """The one place a scan's backend and interpret mode are decided: the
+    compiled kernel exactly on a TPU, unless the caller says otherwise."""
+    assert resolve_impl(impl, interpret, int4=int4, platform=platform) == want
+
+
+@pytest.mark.parametrize("impl,int4", [("ref", False), ("numpy", True)])
+def test_resolve_impl_rejects_unknown(impl, int4):
+    with pytest.raises(ValueError, match="unknown retrieval_topk impl"):
+        resolve_impl(impl, int4=int4, platform="tpu")
 
 
 # ---------------------------------------------------------------------------

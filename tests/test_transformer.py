@@ -149,8 +149,12 @@ def test_lora_merge_equals_on_the_fly(setup):
     o2 = T.forward_hidden(PL.merge_lora(params, lora, rc), CFG, rc,
                           tokens=tokens, **FW)["h"]
     # merged weights are exact to one fp32 ulp (float64 merge); the residual
-    # is fp32 forward reassociation, which scales with |h| — hence the rtol
-    np.testing.assert_allclose(o1, o2, atol=2e-3, rtol=5e-4)
+    # is fp32 forward reassociation, whose size follows the largest
+    # activations feeding every sum (the residual stream's |h|), not each
+    # element's own magnitude: bound it by 1e-4 of max|h| (2^-24 rounding
+    # grown through 4 layers of 64-128-term sums; measured 3e-5)
+    err = float(np.max(np.abs(np.asarray(o1) - np.asarray(o2))))
+    assert err <= 1e-4 * float(np.max(np.abs(np.asarray(o1)))), err
 
 
 def test_lora_zero_init_is_identity(setup):
