@@ -1,0 +1,8 @@
+"""scan_roofline.fresh: ``scan_roofline`` in the cells that report
+``fresh_queries_per_s``, which it moves there."""
+from pathlib import Path
+
+from chipbench import spec
+
+read = spec.reader("metrics", "scan_roofline",
+                   bench_dir=Path(__file__).resolve().parents[1])
