@@ -1,5 +1,5 @@
-"""Benchmark orchestrator: one harness per paper table/figure + the roofline
-report. ``python -m benchmarks.run [--only table2_throughput,...]``."""
+"""Benchmark orchestrator: one harness per paper table/figure.
+``python -m benchmarks.run [--only table2_throughput,...]``."""
 from __future__ import annotations
 
 import argparse
@@ -23,7 +23,6 @@ SUITES = [
     ("storage_cost", "§5.4: storage cost"),
     ("store_scale", "Store scaling: insert throughput & query latency"),
     ("check_regression", "Guard: store-scale throughput vs committed baseline"),
-    ("roofline", "§Roofline: dry-run report"),
 ]
 
 

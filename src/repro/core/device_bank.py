@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import spans
 from repro.distributed.collectives import topk_allgather_merge
 from repro.kernels.retrieval_topk.kernel import (
     retrieval_topk_int4_gathered_pallas, retrieval_topk_int4_pallas)
@@ -277,8 +278,9 @@ class DeviceBank:
             packed = scatter(packed, rows32, vals)
             scales = self._scatter_donated(scales, rows32, scs) if private \
                 else self._scatter(scales, rows32, scs)
-            self.h2d_bytes += int(vals.nbytes + scs.nbytes +
-                                  2 * rows32.nbytes)
+            nbytes = int(vals.nbytes + scs.nbytes + 2 * rows32.nbytes)
+            self.h2d_bytes += nbytes
+            spans.count("h2d_bytes", nbytes)
             self.h2d_rows += m
         if private:
             # commit the growth only now that every dispatch above was
@@ -422,7 +424,7 @@ class DeviceBank:
                            tuple(sorted(kw.items())))
         packed, scales, n = state.packed, state.scales, state.n
         k = min(k, n)
-        q = jnp.asarray(np.asarray(queries, np.float32))
+        q = spans.to_device(np.asarray(queries, np.float32))
         impl = self.impl
         if self.n_shards == 1:
             if self.store_int4:
@@ -443,7 +445,8 @@ class DeviceBank:
                                  "at attach_device_bank time")
             s, i = self._sharded_search_fn(k, impl, packed.shape[0])(
                 q, packed, scales, jnp.asarray(n, jnp.int32))
-        return np.asarray(i, np.int64), np.asarray(s, np.float32)
+        return (spans.to_host(i).astype(np.int64, copy=False),
+                spans.to_host(s).astype(np.float32, copy=False))
 
     def _sharded_rows_fn(self, k: int, k_loc: int, impl: str, cap: int,
                          m_width: int):
@@ -564,13 +567,14 @@ class DeviceBank:
         if not self.store_int4:
             raise NotImplementedError("pruned search needs an int4 bank")
         k = min(k, state.n)
-        q = jnp.asarray(np.asarray(queries, np.float32))
+        q = spans.to_device(np.asarray(queries, np.float32))
         if self.n_shards == 1:
             s, i = retrieval_topk_int4_gathered(
                 q, state.packed, state.scales, row_ids, k, normalize=False,
                 impl=self.impl, interpret=self.interpret, n_valid=state.n,
                 **kw)
-            return np.asarray(i, np.int64), np.asarray(s, np.float32)
+            return (spans.to_host(i).astype(np.int64, copy=False),
+                    spans.to_host(s).astype(np.float32, copy=False))
         if kw:
             raise ValueError("sharded DeviceBank.search_gathered takes no "
                              f"kernel kwargs (got {sorted(kw)})")
@@ -583,7 +587,8 @@ class DeviceBank:
                                        row_ids.shape[1])
         s, i = fn(q, state.packed, state.scales, jnp.asarray(row_ids),
                   jnp.asarray(state.n, jnp.int32))
-        return np.asarray(i, np.int64), np.asarray(s, np.float32)
+        return (spans.to_host(i).astype(np.int64, copy=False),
+                spans.to_host(s).astype(np.float32, copy=False))
 
     def search_rows(self, queries: np.ndarray, rows: np.ndarray, k: int,
                     state: Optional[BankSnapshot] = None, **kw
@@ -607,13 +612,14 @@ class DeviceBank:
         assert state is not None, "sync() before search_rows()"
         if not self.store_int4:
             raise NotImplementedError("pruned search needs an int4 bank")
-        q = jnp.asarray(np.asarray(queries, np.float32))
+        q = spans.to_device(np.asarray(queries, np.float32))
         if self.n_shards == 1:
             s, i = retrieval_topk_int4_rows(
                 q, state.packed, state.scales, rows, k, normalize=False,
                 impl=self.impl, interpret=self.interpret, **kw)
             rows = np.asarray(rows, np.int64)
-            return rows[np.asarray(i, np.int64)], np.asarray(s, np.float32)
+            return (rows[spans.to_host(i).astype(np.int64, copy=False)],
+                    spans.to_host(s).astype(np.float32, copy=False))
         if kw:
             raise ValueError("sharded DeviceBank.search_rows takes no "
                              f"kernel kwargs (got {sorted(kw)}); set "

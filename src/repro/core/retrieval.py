@@ -27,12 +27,12 @@ are still accepted and driven one uid at a time.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.store import EmbeddingStore
 
 
@@ -149,7 +149,10 @@ def refine_round(store: EmbeddingStore,
     if budget_mode not in ("successes", "attempts"):
         raise ValueError(budget_mode)
     uids_per_query = [np.asarray(u, np.int64).ravel() for u in uids_per_query]
-    fallbacks = [store.get_embeddings(u) for u in uids_per_query]
+    # one span for the round's fallback reads: a span per query would add
+    # its own cost, under a profiler, to the round it times
+    with spans.span("store.get_embeddings"):
+        fallbacks = [store.get_embeddings(u) for u in uids_per_query]
     if refine_fn is None or not any(u.size for u in uids_per_query):
         return fallbacks, [0] * len(uids_per_query)
     pendings: List[np.ndarray] = []
@@ -221,34 +224,33 @@ def speculative_retrieve(
     ``refine_budget`` caps refinements (query latency budget, Fig. 15);
     ``freshness`` and ``nprobe`` are forwarded to the round-1 store scan
     (async device-bank staleness policy / IVF probe fan-out)."""
-    t0 = time.perf_counter()
-    rounds = speculative_filter(store, query_embs, k, impl=impl,
-                                freshness=freshness, nprobe=nprobe)
-    t1 = time.perf_counter()
-    uids, _ = global_verify(rounds, k)
-    if uids.size:
-        # a stale bank snapshot (async refresh) can surface uids deleted
-        # since its generation; round 3 reads live store rows, so drop the
-        # dead ones here — "no longer exists" is the correct stale answer
-        uids = uids[store.contains(uids)]
-    t2 = time.perf_counter()
-    fine_embs, n_ref = _refine_round(store, uids, refine_fn, refine_budget,
-                                     upgrade)
-    t3 = time.perf_counter()
-
-    if len(fine_embs):
-        scores = fine_embs @ np.asarray(fine_query, np.float32)
-        order = np.argsort(-scores)[:final_k]
-        uids_f, scores_f = uids[order], scores[order]
-    else:
-        uids_f = np.zeros((0,), np.int64)
-        scores_f = np.zeros((0,), np.float32)
-    t4 = time.perf_counter()
+    with spans.span("query.filter") as r1:
+        rounds = speculative_filter(store, query_embs, k, impl=impl,
+                                    freshness=freshness, nprobe=nprobe)
+    with spans.span("query.verify") as r2:
+        uids, _ = global_verify(rounds, k)
+        if uids.size:
+            # a stale bank snapshot (async refresh) can surface uids deleted
+            # since its generation; round 3 reads live store rows, so drop
+            # the dead ones here — "no longer exists" is the correct stale
+            # answer
+            uids = uids[store.contains(uids)]
+    with spans.span("query.refine") as r3:
+        fine_embs, n_ref = _refine_round(store, uids, refine_fn,
+                                         refine_budget, upgrade)
+    with spans.span("query.match") as r4:
+        if len(fine_embs):
+            scores = fine_embs @ np.asarray(fine_query, np.float32)
+            order = np.argsort(-scores)[:final_k]
+            uids_f, scores_f = uids[order], scores[order]
+        else:
+            uids_f = np.zeros((0,), np.int64)
+            scores_f = np.zeros((0,), np.float32)
+    per_round = {"filter": r1.s, "verify": r2.s, "refine": r3.s,
+                 "match": r4.s}
     return RetrievalResult(
         uids=uids_f, scores=scores_f, filtered_uids=uids, n_refined=n_ref,
-        latency_s=t4 - t0,
-        per_round_s={"filter": t1 - t0, "verify": t2 - t1,
-                     "refine": t3 - t2, "match": t4 - t3})
+        latency_s=sum(per_round.values()), per_round_s=per_round)
 
 
 def single_granularity_retrieve(store: EmbeddingStore, query_emb: np.ndarray,

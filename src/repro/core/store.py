@@ -44,8 +44,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.quantize import (dequantize_int4, quantize_int4,
                                  quantize_int4_np)
 
@@ -137,7 +137,9 @@ class EmbeddingStore:
         bit-exact with ``quantize_int4`` and costs zero device dispatches
         (a per-item ``add`` used to pay a jit round-trip here)."""
         if self.store_int4:
-            return quantize_int4_np(embs)
+            with spans.span("store.quantize"):
+                spans.count("quantized_bytes", embs.nbytes)
+                return quantize_int4_np(embs)
         return embs, np.ones((len(embs), 1), np.float32)
 
     # -- mutation ------------------------------------------------------------
@@ -150,6 +152,7 @@ class EmbeddingStore:
                        cached_hs=None if cached_h is None
                        else np.asarray(cached_h, np.float32)[None])
 
+    @spans.spanned("store.add_batch")
     def add_batch(self, uids, embs, exit_idxs, exit_layers, *, modality="",
                   fine: bool = False, cached_hs=None) -> None:
         """Vectorized insert: one quantize call for the embedding batch and
@@ -161,8 +164,10 @@ class EmbeddingStore:
         packed, scales = self._quantize_rows(embs)
         act = None
         if cached_hs is not None:
-            ch = np.asarray(cached_hs, np.float32)  # (B, ..., d)
-            p, s = quantize_int4_np(ch)  # host-side, parity with jnp path
+            with spans.span("store.quantize"):
+                ch = np.asarray(cached_hs, np.float32)  # (B, ..., d)
+                spans.count("quantized_bytes", ch.nbytes)
+                p, s = quantize_int4_np(ch)  # host-side, parity with jnp path
             act = (p, s, tuple(ch.shape[1:]))
         exit_idxs = np.asarray(exit_idxs, np.int32).ravel()
         exit_layers = np.asarray(exit_layers, np.int32).ravel()
@@ -206,6 +211,7 @@ class EmbeddingStore:
         """Permanently replace a coarse embedding with its refined version."""
         self.upgrade_batch([uid], np.asarray(fine_emb, np.float32)[None])
 
+    @spans.spanned("store.upgrade_batch")
     def upgrade_batch(self, uids: Sequence[int], fine_embs: np.ndarray) -> None:
         """Vectorized §5.3 upgrade: requantize the whole batch in one call,
         mark only the touched rows dirty, free their activation cache."""
@@ -345,9 +351,9 @@ class EmbeddingStore:
                 self._dense = self._dense.copy()
                 self._escaped_n = 0
             if self.store_int4:
-                self._dense[rows] = np.asarray(dequantize_int4(
-                    jnp.asarray(self._packed[rows]),
-                    jnp.asarray(self._scales[rows])))
+                self._dense[rows] = spans.to_host(dequantize_int4(
+                    spans.to_device(self._packed[rows]),
+                    spans.to_device(self._scales[rows])))
             else:
                 self._dense[rows] = self._packed[rows]
         self._dirty[:self._n] = False
@@ -381,6 +387,7 @@ class EmbeddingStore:
         out = self.cached_activations([uid])
         return out.get(int(uid))
 
+    @spans.spanned("store.cached_activations")
     def cached_activations(self, uids) -> Dict[int, Tuple[np.ndarray, int]]:
         """Batched dequant of cached activations: one jnp call per distinct
         activation shape instead of one per uid. Returns {uid: (h, layer)}."""
@@ -394,8 +401,8 @@ class EmbeddingStore:
         for shape, group in by_shape.items():
             packed = np.stack([g[1] for g in group])
             scales = np.stack([g[2] for g in group])
-            hs = np.asarray(dequantize_int4(jnp.asarray(packed),
-                                            jnp.asarray(scales)))
+            hs = spans.to_host(dequantize_int4(spans.to_device(packed),
+                                               spans.to_device(scales)))
             for (u, _, _, layer), h in zip(group, hs):
                 out[u] = (h.reshape(shape), layer)
         return out
@@ -542,8 +549,13 @@ class EmbeddingStore:
             self.attach_device_bank()
         bank = self._bank
         rows = self._take_bank_dirty_locked()
-        snap = bank.sync(self._packed, self._scales, self._n, rows,
-                         self._meta["uid"][:self._n].copy())
+        # the scatter is dispatched, not waited for: the scan that follows
+        # waits for it
+        with spans.span("bank.sync_dispatch"):
+            rows0 = bank.h2d_rows
+            snap = bank.sync(self._packed, self._scales, self._n, rows,
+                             self._meta["uid"][:self._n].copy())
+            spans.count("bank_rows", bank.h2d_rows - rows0)
         return bank, snap
 
     # -- IVF coarse-filter index ---------------------------------------------
@@ -693,6 +705,7 @@ class EmbeddingStore:
         idx = idx[np.argsort(-scores[idx])]
         return uids[idx], scores[idx]
 
+    @spans.spanned("store.search_batch")
     def search_batch(self, queries: np.ndarray, k: int, *, impl: str = "auto",
                      freshness: Optional[str] = None,
                      nprobe: Optional[int] = None,
@@ -774,11 +787,11 @@ class EmbeddingStore:
             # (O(log N) compiles), not once per store size
             self.upload_bytes += int(slab.nbytes)  # full fp32 slab, per call
             self.upload_calls += 1
-            s, i = retrieval_topk(jnp.asarray(queries), jnp.asarray(slab),
-                                  k, normalize=False, impl=impl, n_valid=n,
-                                  **kw)
-            idx = np.asarray(i, np.int64)
-            top_s = np.asarray(s, np.float32)
+            s, i = retrieval_topk(spans.to_device(queries),
+                                  spans.to_device(slab), k, normalize=False,
+                                  impl=impl, n_valid=n, **kw)
+            idx = spans.to_host(i).astype(np.int64, copy=False)
+            top_s = spans.to_host(s).astype(np.float32, copy=False)
         return uids[idx], top_s
 
     def _resolve_auto_impl(self) -> str:

@@ -18,7 +18,6 @@ come from repro.core.scheduler's calibrated cost model.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -27,6 +26,7 @@ import numpy as np
 
 from repro.configs.base import MEMConfig, RecallConfig
 from repro.core import preexit as PE
+from repro.core import spans
 from repro.core.scheduler import plan_exit_groups
 from repro.core.store import EmbeddingStore
 from repro.models import imagebind as IB
@@ -37,7 +37,6 @@ from repro.models import transformer as T
 class EngineStats:
     n_embedded: int = 0
     layers_executed: float = 0.0
-    superficial_batches: int = 0
     group_batches: int = 0
     wall_s: float = 0.0
 
@@ -115,9 +114,16 @@ class EmbeddingEngine:
         """Embed everything queued; returns cumulative stats."""
         if not self._queue:
             return self.stats
-        t0 = time.perf_counter()
-        uids = np.array([u for u, _ in self._queue])
-        items = np.stack([x for _, x in self._queue])
+        with spans.span("engine.drain") as sp:
+            spans.count("items", len(self._queue))
+            self._drain_queue()
+        self.stats.wall_s += sp.s
+        return self.stats
+
+    def _drain_queue(self) -> None:
+        with spans.span("engine.stack"):
+            uids = np.array([u for u, _ in self._queue])
+            items = np.stack([x for _, x in self._queue])
         self._queue.clear()
         N = self.recall.superficial_layers
 
@@ -135,20 +141,22 @@ class EmbeddingEngine:
         # hidden states; branchynet also starts from layer 0 per sample.
         h_sup_parts, pooled_parts = [], []
         for i in range(0, len(items), self.max_batch):
-            h, pooled = self._jit_superficial(
-                self.params, self.lora,
-                jnp.asarray(items[i:i + self.max_batch]))
-            h_sup_parts.append(np.asarray(h))
-            pooled_parts.append(np.asarray(pooled))
-            self.stats.superficial_batches += 1
-        h_sup = np.concatenate(h_sup_parts)
-        pooled_all = np.concatenate(pooled_parts, axis=1)  # (N, B, d)
+            with spans.span("engine.superficial"):
+                h, pooled = self._jit_superficial(
+                    self.params, self.lora,
+                    spans.to_device(items[i:i + self.max_batch]))
+                h_sup_parts.append(spans.to_host(h))
+                pooled_parts.append(spans.to_host(pooled))
+        with spans.span("engine.concat"):
+            h_sup = np.concatenate(h_sup_parts)
+            pooled_all = np.concatenate(pooled_parts, axis=1)  # (N, B, d)
 
         if self.policy == "recall":
             assert self.predictor is not None, "recall policy needs a predictor"
-            pred_idx = np.asarray(PE.predict_exit(
-                self.predictor, jnp.asarray(pooled_all[-1]),
-                n_exits=len(self.exits)))
+            with spans.span("engine.predict"):
+                pred_idx = spans.to_host(PE.predict_exit(
+                    self.predictor, spans.to_device(pooled_all[-1]),
+                    n_exits=len(self.exits)))
         elif self.policy == "branchynet":
             # confidence-style: run each sample layer-by-layer (batch=1) and
             # exit when consecutive exit embeddings agree (cos > tau).
@@ -158,18 +166,20 @@ class EmbeddingEngine:
         tp = self.params["towers"][self.modality]
         plan = plan_exit_groups(pred_idx, self.exits, N)
         for exit_idx, exit_layer, ids in plan.batches(self.max_batch):
-            if exit_layer <= N:
-                # exit depth within the superficial prefix: embedding comes
-                # straight from the already-computed pooled state (free).
-                embs = np.asarray(T.exit_embedding(
-                    tp, jnp.asarray(pooled_all[exit_layer - 1][ids]),
-                    self.cfg.norm_eps))
-                layers_run = N  # superficial pass was still paid
-            else:
-                fn = self._continue_fn(N, exit_layer)
-                embs = np.asarray(fn(self.params, self.lora,
-                                     jnp.asarray(h_sup[ids])))
-                layers_run = exit_layer
+            with spans.span("engine.continue"):
+                if exit_layer <= N:
+                    # exit depth within the superficial prefix: embedding
+                    # comes straight from the already-computed pooled state
+                    # (free).
+                    embs = spans.to_host(T.exit_embedding(
+                        tp, spans.to_device(pooled_all[exit_layer - 1][ids]),
+                        self.cfg.norm_eps))
+                    layers_run = N  # superficial pass was still paid
+                else:
+                    fn = self._continue_fn(N, exit_layer)
+                    embs = spans.to_host(fn(self.params, self.lora,
+                                            spans.to_device(h_sup[ids])))
+                    layers_run = exit_layer
             self.stats.group_batches += 1
             self.stats.layers_executed += float(len(ids) * layers_run)
             cached = h_sup[ids] if self.cache_activations else None
@@ -183,8 +193,6 @@ class EmbeddingEngine:
         # path (EdgeRAG-style index maintenance hidden behind serving)
         self.store.kick_bank_refresh()
         self.stats.n_embedded += len(uids)
-        self.stats.wall_s += time.perf_counter() - t0
-        return self.stats
 
     def _branchynet_exits(self, items: np.ndarray, tau: float = 0.95) -> np.ndarray:
         """Per-sample confidence exits (baseline; no batching by design)."""
@@ -232,9 +240,11 @@ class EmbeddingEngine:
             for us in groups.values():
                 for i in range(0, len(us), self.max_batch):
                     chunk = us[i:i + self.max_batch]
-                    h = np.stack([cached[u][0] for u in chunk])
-                    embs = np.asarray(fn(self.params, self.lora,
-                                         jnp.asarray(h)))
+                    with spans.span("engine.refine_stack"):
+                        h = np.stack([cached[u][0] for u in chunk])
+                    with spans.span("engine.refine_continue"):
+                        embs = spans.to_host(fn(self.params, self.lora,
+                                                spans.to_device(h)))
                     out.update(zip(chunk, embs))
             if scalar:
                 return out.get(int(uids))

@@ -20,7 +20,6 @@ Two entry points:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -28,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import MEMConfig, RecallConfig
+from repro.core import spans
 from repro.core.retrieval import (RetrievalResult, global_verify,
                                   refine_round, single_granularity_retrieve,
                                   speculative_retrieve)
@@ -126,8 +126,9 @@ class QueryEngine:
     def embed_query_batch(self, queries: np.ndarray) -> np.ndarray:
         """(B, ...) query batch -> (B, G, E) granularity embeddings from ONE
         tower pass (row -1 is the fine/full-depth embedding)."""
-        embs = np.asarray(self._jit_all_exits(self.params, self.lora,
-                                              jnp.asarray(queries)))
+        with spans.span("query.embed"):
+            embs = spans.to_host(self._jit_all_exits(
+                self.params, self.lora, spans.to_device(queries)))
         return embs[self._g_rows].transpose(1, 0, 2)  # (B, G, E)
 
     # -- single query --------------------------------------------------------
@@ -138,10 +139,10 @@ class QueryEngine:
         by_g = self.embed_query(query)
         fine = by_g[self.granularities[-1]]
         if not speculative:
-            t0 = time.perf_counter()
-            uids, scores = single_granularity_retrieve(self.store, fine, k)
+            with spans.span("query.filter") as r1:
+                uids, scores = single_granularity_retrieve(self.store, fine, k)
             return RetrievalResult(uids=uids, scores=scores, filtered_uids=uids,
-                                   n_refined=0, latency_s=time.perf_counter() - t0,
+                                   n_refined=0, latency_s=r1.s,
                                    per_round_s={})
         return speculative_retrieve(
             self.store, [by_g[g] for g in self.granularities], fine,
@@ -161,75 +162,93 @@ class QueryEngine:
         B = len(queries)
         if B == 0:
             return []
-        t0 = time.perf_counter()
-        QG = self.embed_query_batch(queries)            # (B, G, E)
-        fine_q = QG[:, -1]                              # (B, E)
-        G = QG.shape[1]
-        if not speculative:
+        with spans.span("query.query_batch"):
+            spans.count("queries", B)
+            if not speculative:
+                return self._fine_batch(queries, k)
+            return self._speculative_batch(queries, k, final_k,
+                                           refine_budget)
+
+    def _fine_batch(self, queries, k: int) -> List[RetrievalResult]:
+        """``query_batch(speculative=False)``: the fine embedding alone."""
+        B = len(queries)
+        with spans.span("query.filter") as r1:
+            fine_q = self.embed_query_batch(queries)[:, -1]
             uids, scores = self.store.search_batch(fine_q, k,
                                                    impl=self.search_impl,
                                                    freshness=self.freshness,
                                                    nprobe=self.nprobe)
-            dt = (time.perf_counter() - t0) / B
-            # drop IVF padding slots (uid -1 / score -1e30): no exhaustive
-            # path ever emits them, so callers must never see them here
-            live = scores > -5e29
-            return [RetrievalResult(uids=uids[b][live[b]],
-                                    scores=scores[b][live[b]],
-                                    filtered_uids=uids[b][live[b]],
-                                    n_refined=0,
-                                    latency_s=dt, per_round_s={})
-                    for b in range(B)]
+        dt = r1.s / B
+        # drop IVF padding slots (uid -1 / score -1e30): no exhaustive
+        # path ever emits them, so callers must never see them here
+        live = scores > -5e29
+        return [RetrievalResult(uids=uids[b][live[b]],
+                                scores=scores[b][live[b]],
+                                filtered_uids=uids[b][live[b]],
+                                n_refined=0,
+                                latency_s=dt, per_round_s={})
+                for b in range(B)]
 
+    def _speculative_batch(self, queries, k: int, final_k: int,
+                           refine_budget: Optional[int]
+                           ) -> List[RetrievalResult]:
+        B = len(queries)
         # round 1: every (query, granularity) pair in ONE fused store scan
         # (stale-tolerant under the async bank policy: rounds 2+3 verify and
         # re-score the candidates against live embeddings anyway)
-        flat_u, flat_s = self.store.search_batch(
-            QG.reshape(B * G, -1), k, impl=self.search_impl,
-            freshness=self.freshness, nprobe=self.nprobe)
-        kk = flat_u.shape[1]
-        u3 = flat_u.reshape(B, G, kk)
-        s3 = flat_s.reshape(B, G, kk)
-        t1 = time.perf_counter()
+        with spans.span("query.filter") as r1:
+            QG = self.embed_query_batch(queries)        # (B, G, E)
+            fine_q = QG[:, -1]                          # (B, E)
+            G = QG.shape[1]
+            flat_u, flat_s = self.store.search_batch(
+                QG.reshape(B * G, -1), k, impl=self.search_impl,
+                freshness=self.freshness, nprobe=self.nprobe)
+            kk = flat_u.shape[1]
+            u3 = flat_u.reshape(B, G, kk)
+            s3 = flat_s.reshape(B, G, kk)
 
         # round 2: vectorized dedup per query; drop uids deleted since the
         # (possibly stale, under the async bank policy) scanned generation —
         # round 3 reads live store rows. ONE contains() call (= one store
         # lock acquisition) for the whole batch, sliced back per query.
-        cands = [global_verify(list(zip(u3[b], s3[b])), k) for b in range(B)]
-        lens = [u.size for u, _ in cands]
-        if sum(lens):
-            live_all = self.store.contains(
-                np.concatenate([u for u, _ in cands]))
-            offs = np.cumsum([0] + lens)
-            cands = [(u[live_all[o:o + n]], s[live_all[o:o + n]])
-                     for (u, s), o, n in zip(cands, offs, lens)]
-        t2 = time.perf_counter()
+        with spans.span("query.verify") as r2:
+            cands = [global_verify(list(zip(u3[b], s3[b])), k)
+                     for b in range(B)]
+            lens = [u.size for u, _ in cands]
+            if sum(lens):
+                live_all = self.store.contains(
+                    np.concatenate([u for u, _ in cands]))
+                offs = np.cumsum([0] + lens)
+                cands = [(u[live_all[o:o + n]], s[live_all[o:o + n]])
+                         for (u, s), o, n in zip(cands, offs, lens)]
 
         # round 3: one deduplicated refinement batch across all queries
         # (shared retrieval.refine_round core; "attempts" = per-query budget
         # caps attempted candidates, no retry loop)
-        fine_per_q, n_ref_per_q = refine_round(
-            self.store, [u for u, _ in cands], self.refine_fn, refine_budget,
-            upgrade=True, budget_mode="attempts")
-        t3 = time.perf_counter()
+        with spans.span("query.refine") as r3:
+            fine_per_q, n_ref_per_q = refine_round(
+                self.store, [u for u, _ in cands], self.refine_fn,
+                refine_budget, upgrade=True, budget_mode="attempts")
 
-        ranked = []
-        for b in range(B):
-            uids_b, _ = cands[b]
-            fine_embs = fine_per_q[b]
-            n_ref = n_ref_per_q[b]
-            if len(fine_embs):
-                scores = fine_embs @ fine_q[b]
-                order = np.argsort(-scores)[:final_k]
-                ranked.append((uids_b[order], scores[order], uids_b, n_ref))
-            else:
-                ranked.append((np.zeros((0,), np.int64),
-                               np.zeros((0,), np.float32), uids_b, n_ref))
-        t4 = time.perf_counter()
-        per_round = {"filter": (t1 - t0) / B, "verify": (t2 - t1) / B,
-                     "refine": (t3 - t2) / B, "match": (t4 - t3) / B}
+        with spans.span("query.match") as r4:
+            ranked = []
+            for b in range(B):
+                uids_b, _ = cands[b]
+                fine_embs = fine_per_q[b]
+                n_ref = n_ref_per_q[b]
+                if len(fine_embs):
+                    scores = fine_embs @ fine_q[b]
+                    order = np.argsort(-scores)[:final_k]
+                    ranked.append((uids_b[order], scores[order], uids_b,
+                                   n_ref))
+                else:
+                    ranked.append((np.zeros((0,), np.int64),
+                                   np.zeros((0,), np.float32), uids_b, n_ref))
+        per_round = {name: r.s / B for name, r in
+                     (("filter", r1), ("verify", r2), ("refine", r3),
+                      ("match", r4))}
+        latency = sum(per_round.values())
         return [RetrievalResult(uids=u, scores=s, filtered_uids=fu,
-                                n_refined=n, latency_s=(t4 - t0) / B,
+                                n_refined=n, latency_s=latency,
                                 per_round_s=dict(per_round))
                 for u, s, fu, n in ranked]
