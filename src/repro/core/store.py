@@ -30,7 +30,10 @@ scanned by the fused dequant-top-k kernel so neither the fp32 bank nor the
 the numpy matmul path (the interpret-mode kernel loses to BLAS); the device
 path still works there (``impl='device'``) and is what the tests exercise.
 Quantization for inserts runs on the pure-numpy parity path
-(``quantize_int4_np``): no device dispatch per ``add``/``add_batch``.
+(``quantize_int4_np``): no device dispatch per ``add``/``add_batch``. Cached
+activations handed over on the device (one ``jax.Array`` per item) are
+quantized there by the bit-exact ``quantize_int4``: only codes and scales
+cross.
 Queried items are permanently upgraded to fine-grained embeddings (§5.3
 "web cookie" rule) via ``upgrade``/``upgrade_batch``.
 """
@@ -44,10 +47,16 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import spans
 from repro.core.quantize import (dequantize_int4, quantize_int4,
                                  quantize_int4_np)
+
+# cached states on the device, one (..., d) array per item: one program a
+# group size
+_quantize_items = jax.jit(lambda hs: quantize_int4(jnp.stack(hs)))
+
 
 _META_DTYPE = np.dtype([("uid", np.int64), ("exit_idx", np.int32),
                         ("exit_layer", np.int32), ("fine", np.bool_),
@@ -163,12 +172,20 @@ class EmbeddingStore:
         embs = np.asarray(embs, np.float32).reshape(len(uids), self.embed_dim)
         packed, scales = self._quantize_rows(embs)
         act = None
-        if cached_hs is not None:
+        if cached_hs is not None:  # (B, ..., d), or B (..., d) device arrays
             with spans.span("store.quantize"):
-                ch = np.asarray(cached_hs, np.float32)  # (B, ..., d)
-                spans.count("quantized_bytes", ch.nbytes)
-                p, s = quantize_int4_np(ch)  # host-side, parity with jnp path
-            act = (p, s, tuple(ch.shape[1:]))
+                if (isinstance(cached_hs, (list, tuple)) and cached_hs
+                        and isinstance(cached_hs[0], jax.Array)):
+                    # quantized where it lives: only codes and scales cross
+                    spans.count("device_quantized_bytes",
+                                sum(h.nbytes for h in cached_hs))
+                    p, s = _quantize_items(tuple(cached_hs))
+                    p, s = spans.to_host(p), spans.to_host(s)
+                else:
+                    ch = np.asarray(cached_hs, np.float32)
+                    spans.count("quantized_bytes", ch.nbytes)
+                    p, s = quantize_int4_np(ch)
+            act = (p, s, p.shape[1:-1] + (2 * p.shape[-1],))
         exit_idxs = np.asarray(exit_idxs, np.int32).ravel()
         exit_layers = np.asarray(exit_layers, np.int32).ravel()
         with self._lock:

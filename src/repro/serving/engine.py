@@ -33,6 +33,9 @@ from repro.models import imagebind as IB
 from repro.models import transformer as T
 
 
+_unstack = jax.jit(jnp.unstack)  # (B, ...) -> B arrays, one dispatch
+
+
 @dataclasses.dataclass
 class EngineStats:
     n_embedded: int = 0
@@ -166,6 +169,7 @@ class EmbeddingEngine:
         tp = self.params["towers"][self.modality]
         plan = plan_exit_groups(pred_idx, self.exits, N)
         for exit_idx, exit_layer, ids in plan.batches(self.max_batch):
+            hs = None  # the group's superficial states on the device
             with spans.span("engine.continue"):
                 if exit_layer <= N:
                     # exit depth within the superficial prefix: embedding
@@ -177,16 +181,19 @@ class EmbeddingEngine:
                     layers_run = N  # superficial pass was still paid
                 else:
                     fn = self._continue_fn(N, exit_layer)
-                    embs = spans.to_host(fn(self.params, self.lora,
-                                            spans.to_device(h_sup[ids])))
+                    hs = spans.to_device(h_sup[ids])
+                    embs = spans.to_host(fn(self.params, self.lora, hs))
                     layers_run = exit_layer
             self.stats.group_batches += 1
             self.stats.layers_executed += float(len(ids) * layers_run)
-            cached = h_sup[ids] if self.cache_activations else None
+            cached = None
+            if self.cache_activations:
+                # the store quantizes them on the device, one array an item
+                cached = _unstack(spans.to_device(h_sup[ids]) if hs is None
+                                  else hs)
             self.store.add_batch(
                 uids[ids], embs, [exit_idx] * len(ids), [exit_layer] * len(ids),
-                modality=self.modality,
-                cached_hs=cached if cached is not None else None)
+                modality=self.modality, cached_hs=cached)
         # under an async bank-refresh policy, kick the scheduler now: the
         # freshly inserted rows scatter to the device while the host is
         # still between drains, instead of on the first query's critical

@@ -48,13 +48,65 @@ def run_py(code: str, n_dev: int = 8, timeout: int = 600):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(1, 8), (64, 32), (5, 7, 16)])
-def test_quantize_int4_np_bit_exact_parity(shape):
+def _near_half_steps(m, n, rng, dtype):
+    """(rows, n) values of ``dtype`` at (k + 0.5) * scale and one ulp of
+    ``dtype`` either side, scale = max / 7 of each row's max ``m``."""
+    m = np.asarray(m, np.float32).reshape(-1, 1)
+    scale = np.maximum(m / np.float32(7), np.float32(1e-12))
+    k = rng.integers(-8, 7, (len(m), n)).astype(np.float32)
+    v = ((k + np.float32(0.5)) * scale).astype(dtype)
+    inf = np.asarray(np.inf, dtype)
+    side = rng.integers(-1, 2, v.shape)
+    v = np.where(side > 0, np.nextafter(v, inf) if dtype == np.float32
+                 else (v.astype(np.float32) * (1 + 2.0 ** -8)).astype(dtype),
+                 v)
+    v = np.where(side < 0, np.nextafter(v, -inf) if dtype == np.float32
+                 else (v.astype(np.float32) * (1 - 2.0 ** -8)).astype(dtype),
+                 v)
+    return np.clip(v.astype(np.float32), -m, m).astype(dtype)
+
+
+def _parity_input(shape):
     rng = np.random.default_rng(0)
+    if shape == (4096, 32):         # float32 rows of near-half steps
+        m = rng.uniform(0.5, 2, shape[0]) * 10.0 ** rng.integers(-8, 8,
+                                                                 shape[0])
+        x = _near_half_steps(m, shape[1], rng, np.float32)
+        x[:, 0] = m
+        return x
+    if shape[-2:] != (257, 1280):   # small float32 rows of mixed magnitude
+        x = (rng.standard_normal(shape) *
+             rng.choice([1e-6, 1.0, 100.0], shape)).astype(np.float32)
+        x[..., 0] = 0.0  # exercise the zero / tiny-scale guard
+        return x
+    # superficial states as the tower leaves them: bf16, one row all zero,
+    # rows whose max is 7 * 2**e (exact half-step ties) or seeded, holding
+    # values a bf16 ulp either side of (k + 0.5) * scale
     x = (rng.standard_normal(shape) *
-         rng.choice([1e-6, 1.0, 100.0], shape)).astype(np.float32)
-    x[..., 0] = 0.0  # exercise the zero / tiny-scale guard
-    pj, sj = quantize_int4(jnp.asarray(x))
+         rng.choice([1e-3, 1.0, 30.0], shape[:-1] + (1,))).astype(jnp.bfloat16)
+    x[0, 0] = 0
+    rows = x[:, 1:65].reshape(-1, shape[-1])
+    m = np.where(rng.random(len(rows)) < 0.5,
+                 7 * 2.0 ** rng.integers(-10, 6, len(rows)),
+                 np.abs(rows.astype(np.float32)).max(-1)).astype(np.float32)
+    rows[:, :256] = _near_half_steps(m, 256, rng, jnp.bfloat16)
+    rows[:, 256] = m
+    x[:, 1:65] = rows.reshape(shape[0], 64, shape[-1])
+    return x
+
+
+@pytest.mark.parametrize("shape", [(1, 8), (64, 32), (5, 7, 16), (4096, 32),
+                                   (8, 257, 1280), (64, 257, 1280)])
+def test_quantize_int4_np_bit_exact_parity(shape):
+    """The device rule is the numpy rule, bit for bit: small float32 rows,
+    float32 values an ulp from a half step, and the ingest cell's bf16
+    superficial states (a full 64-item chunk of them on a TPU only)."""
+    if shape[0] == 64 and len(shape) == 3 and jax.default_backend() != "tpu":
+        pytest.skip("a 64-item chunk of cell-width states: TPU only")
+    x = _parity_input(shape)
+    # compiled, as every caller on the served path runs it (XLA may fold
+    # the division by 7 into a product there)
+    pj, sj = jax.jit(quantize_int4)(jnp.asarray(x))
     pn, sn = quantize_int4_np(x)
     np.testing.assert_array_equal(np.asarray(pj), pn)
     np.testing.assert_array_equal(np.asarray(sj), sn)
@@ -70,6 +122,50 @@ def test_quantize_int4_np_half_even_rounding():
     pj, _ = quantize_int4(jnp.asarray(h))
     pn, _ = quantize_int4_np(h)
     np.testing.assert_array_equal(np.asarray(pj), pn)
+
+
+def test_store_quantizes_device_states_as_host_states(monkeypatch):
+    """Cached states handed over on the device and on the host are stored
+    alike; only the device input counts ``device_quantized_bytes``."""
+    from repro.core import spans
+    on = [False]
+    monkeypatch.setattr(spans, "_collecting", lambda: on[0])
+    h = _parity_input((8, 257, 1280))[:4, :, :64]
+    embs = _embs(4, 16)
+    stores, windows = [], []
+    # one device array per item (as a drain hands them over), then the host
+    # array; one profiler session each
+    for cached in ([jnp.asarray(r) for r in h], h):
+        st = EmbeddingStore(16, capacity=4)
+        on[0] = True
+        with spans.span("root"):
+            st.add_batch(np.arange(4), embs, [0] * 4, [2] * 4,
+                         cached_hs=cached)
+        on[0] = False
+        stores.append(st)
+        windows.append(spans.window())
+    (dev, host), (wd, wh) = stores, windows
+    for u in range(4):
+        for a, b in zip(dev._act_cache[u][:2], host._act_cache[u][:2]):
+            assert type(a) is np.ndarray and a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        assert dev._act_cache[u][2:] == host._act_cache[u][2:] == \
+            ((257, 64), 2)
+    ad, ah = dev.cached_activations(range(4)), host.cached_activations(range(4))
+    for u in range(4):
+        np.testing.assert_array_equal(ad[u][0], ah[u][0])
+        np.testing.assert_array_equal(ad[u][0], dequantize_int4_np(
+            *quantize_int4_np(h[u])))
+    np.testing.assert_array_equal(dev.get_embeddings(np.arange(4)),
+                                  host.get_embeddings(np.arange(4)))
+    assert wd["store.quantize"]["device_quantized_bytes"] == h.nbytes
+    assert wd["store.quantize"]["quantized_bytes"] == embs.nbytes
+    # only the codes and scales come down
+    assert wd["store.quantize"]["d2h_bytes"] == 4 * 257 * (32 + 4)
+    assert "device_quantized_bytes" not in wh["store.quantize"]
+    assert "d2h_bytes" not in wh["store.quantize"]
+    assert wh["store.quantize"]["quantized_bytes"] == \
+        4 * h.size + embs.nbytes
 
 
 def test_store_add_runs_without_device_dispatch():

@@ -86,6 +86,46 @@ def test_refine_fn_reproduces_full_embedding(service):
     assert cos > 0.85, cos
 
 
+@pytest.mark.parametrize("past_prefix", [1, 0])
+def test_drain_caches_states_quantized_on_the_device(service, monkeypatch,
+                                                     past_prefix):
+    """A drain stores each item's cached state as the int4 rule of the
+    superficial state's host copy, quantized on the device: the host rule
+    never sees the states, only the embeddings. Items exiting past the
+    superficial prefix (the continuation's upload) and within it."""
+    from repro.core import store as store_mod
+    params, predictor, data = service
+    eng = _engine(params, predictor, policy="fixed")
+    eng.fixed_exit = RC.superficial_layers + past_prefix
+    assert eng.fixed_exit in eng.exits
+    sup, host_rule_shapes = [], []
+    superficial = eng._jit_superficial
+
+    def keep(p, lo, x):
+        h, pooled = superficial(p, lo, x)
+        sup.append(np.asarray(h))
+        return h, pooled
+    host_rule = store_mod.quantize_int4_np
+
+    def watch(x):
+        host_rule_shapes.append(np.shape(x))
+        return host_rule(x)
+    monkeypatch.setattr(eng, "_jit_superficial", keep)
+    monkeypatch.setattr(store_mod, "quantize_int4_np", watch)
+    eng.submit_batch(np.arange(40), data.items["vision"][:40])
+    eng.drain()
+    h = np.concatenate(sup)
+    assert len(sup) == 3 and h.shape[0] == 40
+    assert host_rule_shapes and all(len(s) == 2 and s[1] == CFG.embed_dim
+                                    for s in host_rule_shapes)
+    packed, scale = host_rule(h)
+    for u in range(40):
+        p, sc, shape, layer = eng.store._act_cache[u]
+        assert shape == h.shape[1:] and layer == eng.fixed_exit
+        np.testing.assert_array_equal(p, packed[u])
+        np.testing.assert_array_equal(sc, scale[u])
+
+
 def test_query_upgrade_on_query(service):
     params, predictor, data = service
     eng = _engine(params, predictor)

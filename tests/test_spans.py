@@ -228,6 +228,13 @@ def test_drain_byte_counters_are_the_crossing_arrays(model, collecting,
             return out
         return run
     monkeypatch.setattr(eng, "_continue_fn", continue_fn)
+    quantize = store_mod._quantize_items
+
+    def quantize_on_device(hs):
+        packed, scale = quantize(hs)
+        down.extend([packed, scale])
+        return packed, scale
+    monkeypatch.setattr(store_mod, "_quantize_items", quantize_on_device)
     eng.submit_batch(np.arange(48), data.items["vision"][:48])
     eng.drain()
     w = spans.window()
@@ -237,10 +244,15 @@ def test_drain_byte_counters_are_the_crossing_arrays(model, collecting,
     assert d["d2h_bytes"] == _nbytes(*down)
     assert (w["engine.superficial"]["h2d_bytes"] +
             w["engine.continue"]["h2d_bytes"] == d["h2d_bytes"])
-    # the float32 bytes the store quantized: embeddings and cached states
-    S = CFG.tower("vision").n_tokens + 1
-    assert w["store.add_batch"]["quantized_bytes"] == \
-        4 * 48 * (CFG.embed_dim + S * CFG.tower("vision").d_model)
+    # the store quantized the float32 embeddings on the host and the
+    # cached states (as the tower left them) on the device: their codes
+    # and scales are the store's crossings
+    S, dm = CFG.tower("vision").n_tokens + 1, CFG.tower("vision").d_model
+    assert w["store.add_batch"]["quantized_bytes"] == 4 * 48 * CFG.embed_dim
+    assert w["store.quantize"]["device_quantized_bytes"] == \
+        48 * S * dm * np.dtype(CFG.dtype).itemsize
+    assert w["store.quantize"]["d2h_bytes"] == 48 * S * (dm // 2 + 4)
+    assert "h2d_bytes" not in w["store.quantize"]
     assert eng.stats.wall_s == pytest.approx(d["s"])
     assert eng.stats.group_batches == w["engine.continue"]["n"]
 
