@@ -1,5 +1,6 @@
-"""Seeded data for every traffic mix: vision items, query token ids, bank
-filler rows, placed rows and the fresh rows' cached activations.
+"""Seeded data for every traffic mix: ingest items (patch features or token
+ids), query token ids, bank filler rows, placed rows and the fresh rows'
+cached activations.
 
 All of it is a function of ``--seed`` alone. Large arrays are drawn on the
 device in chunks keyed by ``fold_in(seed, tag, chunk)``, so any chunk can
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 TAG_ITEMS, TAG_ACTS = 1, 2
+TAG_TOKENS = 8                 # host draws: 3 query ids, 4 filler, 5-7 loops
 FILLER_UID0 = 10 ** 9          # filler row i has uid FILLER_UID0 + i
 FRESH_UID0 = 2 * 10 ** 9       # query i's fresh coarse row
 PLACED_UID0 = 3 * 10 ** 9      # query i's fine row j: PLACED_UID0 + 16 i + j
@@ -45,6 +47,17 @@ def item_pool(seed: int, n: int, n_tokens: int, d_input: int,
         device_normal_bf16(seed, TAG_ITEMS, c, (min(chunk, n - c * chunk),
                                                 n_tokens, d_input))
         for c in range(-(-n // chunk))])
+
+
+def ingest_pool(seed: int, n: int, t: dict) -> np.ndarray:
+    """``n`` seeded items for the tower ``t``: (n, n_tokens) int32 token ids
+    for a vocabulary tower, patch features (``item_pool``) for a
+    stub-frontend one."""
+    if t["vocab"]:
+        return rng(seed, TAG_TOKENS).integers(0, t["vocab"],
+                                              (n, t["n_tokens"]),
+                                              dtype=np.int32)
+    return item_pool(seed, n, t["n_tokens"], t["d_input"])
 
 
 def fresh_activations(seed: int, drain: int, batch: int, n_tokens: int,

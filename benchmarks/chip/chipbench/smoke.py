@@ -3,8 +3,9 @@ smoke widths, a small bank, and cells named ``tiny.*``.
 
 ``make_tree(root)`` writes ``root/BENCHMARK.json`` and
 ``root/benchmarks/chip/{configs,traffic,limits}`` with the real loops,
-metric readers and peaks copied in, so a test can also add files of its own
-there (a dummy loop, cell or metric) without touching the real ones.
+reference blocks, metric readers and peaks copied in, so a test can also
+add files of its own there (a dummy loop, block, cell or metric) without
+touching the real ones.
 """
 from __future__ import annotations
 
@@ -57,7 +58,7 @@ def make_tree(root: Path) -> Path:
     bench = root / "benchmarks" / "chip"
     for sub in ("configs", "traffic", "limits"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
-    for sub in ("loops", "end_to_end", "metrics"):
+    for sub in ("loops", "reference", "end_to_end", "metrics"):
         shutil.copytree(spec.BENCH_DIR / sub, bench / sub, dirs_exist_ok=True,
                         ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(spec.BENCH_DIR / "peaks.json", bench / "peaks.json")

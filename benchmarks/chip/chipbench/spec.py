@@ -6,7 +6,12 @@ limits or one per-layer metric is a file of its own under the benchmark's
 directory, so a later cell or metric is an added file and an added entry,
 never an edit:
 
-  configs/<config>.json    sizes, source, reduced/assumed/departures
+  configs/<config>.json    sizes, source, reduced/assumed/departures; an
+                           optional top-level ``"blocks"``: {modality:
+                           block} for towers not of the block ``tower``
+  reference/<block>.py     a tower block's plain reference: its leaves,
+                           ``Tower`` and operation counts (see
+                           ``reference/tower.py``)
   traffic/<traffic>.json   parameters of the mix; ``"loop"`` names its loop
   loops/<loop>.py          ``class Loop(base.Loop)``: data and drains of one
                            kind of traffic (see ``chipbench/base.py``)
@@ -121,10 +126,21 @@ def loop_class(name: str, *, bench_dir: Path = BENCH_DIR):
     return _module("loops", name, bench_dir).Loop
 
 
+def block(config: dict, modality: str, *, bench_dir: Path = BENCH_DIR):
+    """The reference block module of ``config``'s ``modality`` tower:
+    ``reference/<name>.py`` for the name the configuration's top-level
+    ``"blocks"`` gives that modality, ``reference/tower.py`` where it gives
+    none."""
+    return _module("reference", config.get("blocks", {}).get(modality,
+                                                             "tower"),
+                   bench_dir)
+
+
 def arch_spec(config: dict):
     """The program's ``ArchSpec`` for a configuration file, built with the
     program's own config classes (so the served path sees exactly what a
-    registered architecture would give it)."""
+    registered architecture would give it). The harness's own keys, such
+    as ``"blocks"``, stay out of it."""
     from repro.configs.base import (ArchSpec, MEMConfig, RecallConfig,
                                     ShapeConfig, TowerConfig)
     m = config["model"]

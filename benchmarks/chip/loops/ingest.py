@@ -1,12 +1,14 @@
 """Ingest: back-to-back ``EmbeddingEngine.drain`` calls, each of
-``items_per_drain`` seeded vision items, every item exiting at
-``exit_layer`` (``policy="fixed"``), so the continuation has one shape.
+``items_per_drain`` seeded items of the traffic's modality, every item
+exiting at ``exit_layer`` (``policy="fixed"``), so the continuation has one
+shape. The tower's reference, control and operation counts are its block's
+(``spec.block``).
 
-Traffic keys: ``items_per_drain``, ``item_pool`` (distinct seeded items
-the drains draw from), ``exit_layer``, ``kept_per_chunk`` (items of each
-engine chunk whose stored row and cached state the check keeps),
-``check_items`` (of those, how many go through the reference tower),
-``warmup_drains``.
+Traffic keys: ``modality`` (default ``"vision"``), ``items_per_drain``,
+``item_pool`` (distinct seeded items the drains draw from), ``exit_layer``,
+``kept_per_chunk`` (items of each engine chunk whose stored row and cached
+state the check keeps), ``check_items`` (of those, how many go through the
+reference tower), ``warmup_drains``.
 """
 from __future__ import annotations
 
@@ -14,9 +16,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from chipbench import base, counts, data, gaps, spec
+from chipbench import base, data, gaps, spec
 from reference import int4 as R4
-from reference import tower as RT
 
 
 class Loop(base.Loop):
@@ -24,12 +25,17 @@ class Loop(base.Loop):
     SPAN = "drain"
 
     def build(self, engine, query) -> None:
-        self.engine, self.store = engine, engine.store
         tr = self.tr
-        self.vt = spec.tower(self.cfg, "vision")
+        self.mod = _modality(tr)
+        if engine.modality != self.mod:
+            engine = _engine_for(engine, self.mod)
+        self.engine, self.store = engine, engine.store
+        self.t = spec.tower(self.cfg, self.mod)
+        self.block = spec.block(self.cfg, self.mod,
+                                bench_dir=self.cell.bench_dir)
         self.N = self.cfg["recall"]["superficial_layers"]
         self.exit = int(tr["exit_layer"])
-        exits = spec.exit_layers(self.cfg, self.vt["n_layers"])
+        exits = spec.exit_layers(self.cfg, self.t["n_layers"])
         if self.exit not in exits or self.exit <= self.N:
             raise spec.SpecError(f"exit {self.exit} is not an exit past the "
                                  f"superficial prefix ({exits}, N={self.N})")
@@ -37,9 +43,8 @@ class Loop(base.Loop):
         engine.policy, engine.fixed_exit = "fixed", self.exit
         self.per = int(tr["items_per_drain"])
         with self.phase("items"):
-            self.pool = data.item_pool(self.seed, int(tr["item_pool"]),
-                                       self.vt["n_tokens"],
-                                       self.vt["d_input"])
+            self.pool = data.ingest_pool(self.seed, int(tr["item_pool"]),
+                                         self.t)
         self.order = data.rng(self.seed, 5)
         # kept items: seeded slots in each engine chunk of every window drain
         chunk, kpc = engine.max_batch, int(tr["kept_per_chunk"])
@@ -113,9 +118,9 @@ class Loop(base.Loop):
         self.check_uids = np.sort(r.choice(uids, n, replace=False))
 
     def check(self) -> None:
-        """The vision tower against the reference, on the checked items:
+        """The tower against its block's reference, on the checked items:
         the cached superficial state (layer N) and the exit embedding."""
-        ref = RT.Tower(self.cfg, self.seed, "vision")
+        ref = self.block.Tower(self.cfg, self.seed, self.mod)
         gaps_e, gaps_h = [], []
         for lo in range(0, len(self.check_uids), 16):
             us = self.check_uids[lo:lo + 16].tolist()
@@ -130,29 +135,38 @@ class Loop(base.Loop):
         self.readings["sup_gap"] = max(gaps_h) if gaps_h else float("inf")
 
     def work(self, window_s: float, peaks: dict) -> dict:
-        t = self.vt
-        per_layer = counts.layer_flops(t["n_tokens"] + 1, t["d_model"],
-                                       t["d_ff"])
-        items = self.stats1["items"] - self.stats0["items"]
-        layers = self.stats1["layers"] - self.stats0["layers"]
-        flops = (layers * per_layer +
-                 items * counts.frontend_flops(t, self.cfg) +
-                 items * counts.exit_head_flops(t, self.cfg))
+        flops = self.flops(self.cell,
+                           self.stats1["items"] - self.stats0["items"],
+                           self.stats1["layers"] - self.stats0["layers"])
         return {"flops": flops, "mfu": flops / window_s / peaks["bf16_flops"]}
+
+    @staticmethod
+    def flops(cell, items: float, layers: float) -> float:
+        """FLOPs of ``items`` items over ``layers`` tower layers run in all
+        (``EngineStats``): each layer at the mean of the layers [0, exit)
+        that every item runs, plus each item's frontend and exit head."""
+        cfg, mod = cell.config, _modality(cell.traffic)
+        t = spec.tower(cfg, mod)
+        blk = spec.block(cfg, mod, bench_dir=cell.bench_dir)
+        ex = int(cell.traffic["exit_layer"])
+        return (layers * (blk.layer_flops(t, cfg, 0, ex) / ex) +
+                items * blk.frontend_flops(t, cfg) +
+                items * blk.exit_head_flops(t, cfg))
 
     @staticmethod
     def control(cell, seed: int) -> dict:
         """``emb_gap`` and ``sup_gap`` of the float8 tower against the
         float32 one, on ``check_items`` seeded items of the pool."""
         cfg, tr = cell.config, cell.traffic
-        vt = spec.tower(cfg, "vision")
+        mod = _modality(tr)
+        blk = spec.block(cfg, mod, bench_dir=cell.bench_dir)
         N, ex = cfg["recall"]["superficial_layers"], int(tr["exit_layer"])
-        pool = data.item_pool(seed, int(tr["item_pool"]), vt["n_tokens"],
-                              vt["d_input"])
+        pool = data.ingest_pool(seed, int(tr["item_pool"]),
+                                spec.tower(cfg, mod))
         pick = np.sort(data.rng(seed, 7).choice(
             len(pool), int(tr["check_items"]), replace=False))
-        ref, low = RT.Tower(cfg, seed, "vision"), \
-            RT.Tower(cfg, seed, "vision", cast="fp8")
+        ref, low = blk.Tower(cfg, seed, mod), \
+            blk.Tower(cfg, seed, mod, cast="fp8")
         ge, gh = [], []
         for lo in range(0, len(pick), 16):
             x = pool[pick[lo:lo + 16]]
@@ -161,3 +175,19 @@ class Loop(base.Loop):
             ge.append(gaps.emb_gap(eL[ex], e32[ex]))
             gh.append(gaps.rel_gap(hL, h32))
         return {"emb_gap": max(ge), "sup_gap": max(gh)}
+
+
+def _modality(traffic: dict) -> str:
+    return traffic.get("modality", "vision")
+
+
+def _engine_for(engine, modality: str):
+    """The program's engine for ``modality`` over the served weights and
+    store: ``build_service`` builds one for vision, and the fixed policy
+    consults no pre-exit predictor."""
+    from repro.serving.engine import EmbeddingEngine
+    return EmbeddingEngine(engine.params, engine.cfg, engine.recall,
+                           modality=modality, lora=engine.lora,
+                           max_batch=engine.max_batch, store=engine.store,
+                           cache_activations=engine.cache_activations,
+                           fw_kw=engine.fw_kw)

@@ -7,7 +7,8 @@ top ``placed_fresh + placed_fine``; every other row is a filler row at
 ``filler_norm``. Row j of query i is its placed direction scaled by
 1 - 0.01 j, fresh rows first, so round 3 refines exactly the drain's own
 fresh rows, each once for good. The fresh pool holds ``pool_drains``
-drains; a run that outlasts it fails loudly.
+drains; a run that outlasts it fails loudly. The text and vision towers'
+references, controls and operation counts are their blocks' (``spec.block``).
 
 Traffic keys: ``batch``, ``k``, ``bank_rows``, ``filler_norm``,
 ``placed_fine``, ``placed_fresh``, ``own_score`` and ``pool_drains`` (when
@@ -21,7 +22,6 @@ import numpy as np
 
 from chipbench import base, counts, data, gaps, spec
 from reference import int4 as R4
-from reference import tower as RT
 
 MAX_PLACED = 16          # placed rows per query: uid PLACED_UID0 + 16 i + j
 
@@ -39,6 +39,9 @@ class Loop(base.Loop):
         self.E = cfg["model"]["embed_dim"]
         self.tt = spec.tower(cfg, "text")
         self.vt = spec.tower(cfg, "vision")
+        self.tblk, self.vblk = (spec.block(cfg, m,
+                                           bench_dir=self.cell.bench_dir)
+                                for m in ("text", "vision"))
         self.N = cfg["recall"]["superficial_layers"]
         self.grans = spec.query_granularities(cfg, self.tt["n_layers"])
         self.n_fine, self.n_fresh = int(tr["placed_fine"]), \
@@ -238,7 +241,7 @@ class Loop(base.Loop):
         qs, pu, ps = dc["search"]
         qs = np.asarray(qs, np.float32)
         # query tower: every granularity of every query in the drain
-        text = RT.Tower(self.cfg, self.seed, "text")
+        text = self.tblk.Tower(self.cfg, self.seed, "text")
         q_ref = np.zeros((B, G, self.E), np.float32)
         for lo in range(0, B, 16):
             embs, _ = text.run(inputs=self.ids(d)[lo:lo + 16],
@@ -263,7 +266,7 @@ class Loop(base.Loop):
         # round 3: refined fresh rows, continued from the cached state
         refined = {}
         if self.n_fresh:
-            vis = RT.Tower(self.cfg, self.seed, "vision")
+            vis = self.vblk.Tower(self.cfg, self.seed, "vision")
             h = R4.roundtrip(self._acts(d).astype(np.float32))
             e_ref = np.zeros((len(h), self.E), np.float32)
             for lo in range(0, len(h), 16):
@@ -305,17 +308,11 @@ class Loop(base.Loop):
 
     def work(self, window_s: float, peaks: dict) -> dict:
         """Model work per window: query tower, refine continuation, scan."""
-        tt, vt, cfg = self.tt, self.vt, self.cfg
-        B, G = self.B, len(self.grans)
+        cfg, B, G = self.cfg, self.B, len(self.grans)
         n = len(self.drains)
-        tower = n * B * (tt["n_layers"] * counts.layer_flops(
-            tt["n_tokens"] + 1, tt["d_model"], tt["d_ff"]) +
-            len(spec.exit_layers(cfg, tt["n_layers"])) *
-            counts.exit_head_flops(tt, cfg))
-        refined = sum(dc["refined_unique"] for dc in self.drains)
-        refine = refined * ((vt["n_layers"] - self.N) * counts.layer_flops(
-            vt["n_tokens"] + 1, vt["d_model"], vt["d_ff"]) +
-            counts.exit_head_flops(vt, cfg))
+        tower, refine = self.flops(
+            self.cell, n * B,
+            sum(dc["refined_unique"] for dc in self.drains))
         cap = counts.bank_capacity(int(self.tr["bank_rows"]))
         scan_ops, scan_bytes = counts.scan_work(B * G, cap, cfg["model"]
                                                 ["embed_dim"])
@@ -329,6 +326,24 @@ class Loop(base.Loop):
                 "scan_calls": n, "scan_ops": scan_ops,
                 "scan_bytes": scan_bytes, "bank_capacity": cap,
                 "embed_dim": cfg["model"]["embed_dim"]}
+
+    @staticmethod
+    def flops(cell, queries: int, refined: int) -> tuple:
+        """(FLOPs of the query tower for ``queries`` queries, all of its
+        layers and an exit head at each exit; FLOPs of continuing
+        ``refined`` cached vision states from layer N through the last
+        layer and the exit head)."""
+        cfg = cell.config
+        tt, vt = spec.tower(cfg, "text"), spec.tower(cfg, "vision")
+        tb, vb = (spec.block(cfg, m, bench_dir=cell.bench_dir)
+                  for m in ("text", "vision"))
+        N = cfg["recall"]["superficial_layers"]
+        tower = queries * (tb.layer_flops(tt, cfg, 0, tt["n_layers"]) +
+                           len(spec.exit_layers(cfg, tt["n_layers"])) *
+                           tb.exit_head_flops(tt, cfg))
+        refine = refined * (vb.layer_flops(vt, cfg, N, vt["n_layers"]) +
+                            vb.exit_head_flops(vt, cfg))
+        return tower, refine
 
     @staticmethod
     def control(cell, seed: int) -> dict:
@@ -377,8 +392,9 @@ def _control(cell, seed: int) -> dict:
     grans = spec.query_granularities(cfg, tt["n_layers"])
     d = int(tr["warmup_drains"])                   # first window drain
     ids = data.query_ids(seed, d, B, tt["n_tokens"], tt["vocab"])
-    towers = {"ref": RT.Tower(cfg, seed, "text"),
-              "low": RT.Tower(cfg, seed, "text", cast="fp8")}
+    tblk = spec.block(cfg, "text", bench_dir=cell.bench_dir)
+    towers = {"ref": tblk.Tower(cfg, seed, "text"),
+              "low": tblk.Tower(cfg, seed, "text", cast="fp8")}
     qs = {}
     for name, tower in towers.items():
         q = np.zeros((B, len(grans), E), np.float32)
@@ -410,8 +426,9 @@ def _control(cell, seed: int) -> dict:
             seed, d, B * n_fresh, vt["n_tokens"],
             vt["d_model"]).astype(np.float32))
         es = {}
+        vblk = spec.block(cfg, "vision", bench_dir=cell.bench_dir)
         for name, cast in (("ref", "f32"), ("low", "fp8")):
-            tower = RT.Tower(cfg, seed, "vision", cast=cast)
+            tower = vblk.Tower(cfg, seed, "vision", cast=cast)
             e = np.zeros((len(h), E), np.float32)
             for lo in range(0, len(h), 16):
                 embs, _ = tower.run(h_state=h[lo:lo + 16], start=N,

@@ -1,4 +1,7 @@
-"""Plain float32 forward of one embedding tower, as the program defines it.
+"""Plain float32 forward of one embedding tower, as the program defines it:
+the block ``tower``, which every tower uses unless its configuration's
+``"blocks"`` names another (``reference/<block>.py``, looked up by
+``chipbench.spec.block``).
 
 The block (departures from the published ImageBind / CLIP towers are listed
 in each configuration file): tokens -> prepend a learned CLS -> add learned
@@ -8,10 +11,14 @@ MLP (silu(x W_gate) * (x W_up) W_down), each added to the residual stream.
 The embedding at exit ``e`` is the CLS state after layer ``e`` through the
 shared exit head: RMSNorm, a d_model x E projection, L2 normalisation.
 
-Weights come from the seed by the documented initialisation: one PRNG key
-per parameter, split from ``PRNGKey(seed)`` in the sorted order of the
-parameter tree (``logit_scale``, then each tower by modality name, each
-tower's leaves by name), drawn in float32 and rounded to the served dtype.
+A block module exports what the harness asks of every block:
+
+  ``leaves(t, embed_dim)``     the tower's parameters, name -> (shape, init,
+                               arg), drawn by ``reference/weights.py``
+  ``Tower(config, seed, modality, cast=...)`` with ``run(...)`` below
+  ``layer_flops(t, cfg, start, end)``, ``frontend_flops(t, cfg)``,
+  ``exit_head_flops(t, cfg)``  FLOPs of one item through layers
+                               [start, end), its frontend, one exit head
 
 Every matmul runs at ``Precision.HIGHEST``. ``cast="fp8"`` is the control:
 each matmul operand is rounded to float8_e4m3 with a per-tensor scale
@@ -20,25 +27,27 @@ first, the precision a later change might be tempted to serve at.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from pathlib import Path
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from chipbench import counts
+from reference import weights as W
+
 HIGHEST = jax.lax.Precision.HIGHEST
+BENCH_DIR = Path(__file__).resolve().parents[1]   # the other blocks' home
 
 
-# -- weights ---------------------------------------------------------------
-
-def _tower_leaves(t: dict, embed_dim: int
-                  ) -> Dict[str, Tuple[tuple, str, float]]:
+def leaves(t: dict, embed_dim: int) -> Dict[str, Tuple[tuple, str, float]]:
     """name -> (shape, init, scale-or-fan_in) for one tower, names as the
     parameter tree spells them (``/`` between levels)."""
     L, d, h, f = t["n_layers"], t["d_model"], t["n_heads"], t["d_ff"]
     hd = d // h
-    leaves = {
+    out = {
         "cls": ((1, d), "normal", 0.02),
         "exit_head/norm": ((d,), "ones", 0),
         "exit_head/proj": ((d, embed_dim), "fan_in", d),
@@ -55,52 +64,22 @@ def _tower_leaves(t: dict, embed_dim: int
         "pos": ((t["n_tokens"] + 1, d), "normal", 0.02),
     }
     if t["vocab"]:
-        leaves["tok_emb"] = ((t["vocab"], d), "embed", 0.02)
+        out["tok_emb"] = ((t["vocab"], d), "embed", 0.02)
     else:
-        leaves["proj_in"] = ((t["d_input"], d), "fan_in", t["d_input"])
-    return leaves
-
-
-def leaf_order(config: dict) -> List[Tuple[str, str, tuple, str, float]]:
-    """Every parameter in key order: (modality, leaf name, shape, init, arg);
-    ``logit_scale`` first, under modality ''."""
-    m = config["model"]
-    out = [("", "logit_scale", (), "zeros", 0)]
-    for t in sorted(m["towers"], key=lambda t: t["modality"]):
-        # nested dicts flatten by sorted key at every level, which for these
-        # names is the sorted order of the full '/'-joined paths
-        leaves = _tower_leaves(t, m["embed_dim"])
-        for name in sorted(leaves, key=lambda n: n.split("/")):
-            out.append((t["modality"], name) + leaves[name])
+        out["proj_in"] = ((t["d_input"], d), "fan_in", t["d_input"])
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "init", "arg", "dtype"))
-def _draw(key, *, shape, init, arg, dtype):
-    if init == "zeros":
-        return jnp.zeros(shape, dtype)
-    if init == "ones":
-        return jnp.ones(shape, dtype)
-    if init == "normal":
-        return (arg * jax.random.normal(key, shape)).astype(dtype)
-    if init == "embed":
-        return (1.0 * jax.random.normal(key, shape) * arg).astype(dtype)
-    std = 1.0 / np.sqrt(arg)
-    return (std * jax.random.normal(key, shape)).astype(dtype)
+# -- operation counts (chipbench/counts.py has the per-layer formula) -------
+
+def layer_flops(t: dict, cfg: dict, start: int, end: int) -> float:
+    """One item through layers [start, end): every layer alike."""
+    return (end - start) * counts.layer_flops(t["n_tokens"] + 1,
+                                              t["d_model"], t["d_ff"])
 
 
-def tower_weights(config: dict, seed: int, modality: str
-                  ) -> Dict[str, jax.Array]:
-    """One tower's weights from the seed, in the served dtype, as float32."""
-    order = leaf_order(config)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(order))
-    dtype = config["model"]["dtype"]
-    out = {}
-    for key, (mod, name, shape, init, arg) in zip(keys, order):
-        if mod == modality:
-            out[name] = _draw(key, shape=shape, init=init, arg=arg,
-                              dtype=dtype).astype(jnp.float32)
-    return out
+frontend_flops = counts.frontend_flops      # the patch projection, or 0
+exit_head_flops = counts.exit_head_flops    # the d_model x E projection
 
 
 # -- forward ---------------------------------------------------------------
@@ -164,7 +143,7 @@ class Tower:
                       if t["modality"] == modality)
         self.eps = float(config["model"]["norm_eps"])
         self.cast = cast
-        self.w = tower_weights(config, seed, modality)
+        self.w = W.tower_weights(config, seed, modality, BENCH_DIR)
 
     def _layer_weights(self, i: int) -> Dict[str, jax.Array]:
         lw = {n.split("/")[-1]: a[i] for n, a in self.w.items()

@@ -115,13 +115,15 @@ def test_crossings_match_the_shapes(recorded):
     """Per ingest drain of 32 items in chunks of 16 (every item exiting at
     layer 4, past the 2-layer superficial prefix): the items up, the
     superficial state and the per-layer pooled states down, the state up
-    again, the embeddings down."""
+    again, the embeddings down, and the store's int4 codes and scales of
+    the cached states down (the store quantizes them on the device: d/2
+    bytes of nibbles and one float32 scale a token row)."""
     v = CFG.tower("vision")
     S, d, E, N = v.n_tokens + 1, v.d_model, CFG.embed_dim, 2
     w = recorded["ingest"]
     isz = np.dtype(CFG.dtype).itemsize           # the towers' activations
     items = 32 * v.n_tokens * v.d_input * 4
     want = items + 32 * S * d * isz + N * 32 * d * isz + 32 * S * d * isz \
-        + 32 * E * 4
+        + 32 * E * 4 + 32 * S * (d // 2 + 4)
     assert (w["engine.drain"]["h2d_bytes"] + w["engine.drain"]["d2h_bytes"]
             ) / w["engine.drain"]["n"] == want
